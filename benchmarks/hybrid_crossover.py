@@ -22,6 +22,7 @@ CSV rows follow the ``name,us_per_call,derived`` convention.
 from __future__ import annotations
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 
@@ -53,9 +54,9 @@ def _jaxpr_audit(fn, *args):
                 selects += 1
             for v in eq.params.values():
                 sub = None
-                if isinstance(v, jax.core.ClosedJaxpr):
+                if isinstance(v, jex_core.ClosedJaxpr):
                     sub = v.jaxpr
-                elif isinstance(v, jax.core.Jaxpr):
+                elif isinstance(v, jex_core.Jaxpr):
                     sub = v
                 if sub is not None:
                     p, g, s = walk(sub)

@@ -18,14 +18,13 @@ tables), LCA/Euler is mid, the O(1)-table structures trade memory for time.
   too — the old single-device materialization would show up here as a full
   (K, n) spike.
 
-Subprocess per device count (XLA fixes the device count at first jax import).
+Per device count through ``common.per_device_count`` (virtual CPU devices
+in a child process, or the chips this process holds).
 """
 
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
+from collections import defaultdict
 
 import jax
 import jax.numpy as jnp
@@ -60,18 +59,7 @@ def run():
             emit(f"table2/{name}/n={n}", 0.0, f"{mb:.3f}MB_vs_input_{input_mb:.3f}MB")
 
 
-_BUILD_MEM_CHILD = r"""
-import os, numpy as np, jax, jax.numpy as jnp
-from collections import defaultdict
-from repro.core import build as build_mod, distributed
-from repro.launch.mesh import make_mesh
-
-n = int(os.environ["RMQ_BUILDMEM_N"])
-n_dev = len(jax.devices())
-mesh = make_mesh((n_dev,), ("shard",))
-x = jnp.asarray(np.random.default_rng(0).random(n, dtype=np.float32))
-
-def max_device_bytes(tree):
+def _max_device_bytes(tree) -> int:
     by_dev = defaultdict(int)
     seen = set()  # the finalize stage aliases arrays (state -> result):
     for arr in jax.tree_util.tree_leaves(tree):  # count each buffer once
@@ -81,44 +69,43 @@ def max_device_bytes(tree):
                 by_dev[sh.device] += sh.data.nbytes
     return max(by_dev.values()) if by_dev else 0
 
-rep = distributed.build_replicated_st(x, mesh)
-jax.block_until_ready(rep)
-print("replicated", max_device_bytes(rep))
 
-peak = 0
-def observe(stage, state):
-    global peak
-    live = [v for k, v in state.items() if k != "x"]
-    jax.block_until_ready(live)
-    peak = max(peak, max_device_bytes(live))
+def measure_build_mem(devices, n: int):
+    """Max per-device bytes: [(kind, bytes)] for the doubling-table builds."""
+    from repro.core import build as build_mod
+    from repro.core import distributed
+    from repro.launch.mesh import make_group_mesh
 
-sharded = build_mod.build(
-    "sharded_st", x, mesh=mesh, axis_names=("shard",), observer=observe
-)
-print("distributed_build_peak", peak)
-print("sharded_steady", max_device_bytes(sharded))
-"""
+    mesh = make_group_mesh(devices)
+    x = jnp.asarray(np.random.default_rng(0).random(n, dtype=np.float32))
+    rep = distributed.build_replicated_st(x, mesh)
+    jax.block_until_ready(rep)
+    rows = [("replicated", _max_device_bytes(rep))]
+    del rep
+
+    peak = 0
+
+    def observe(stage, state):
+        nonlocal peak
+        live = [v for k, v in state.items() if k != "x"]
+        jax.block_until_ready(live)
+        peak = max(peak, _max_device_bytes(live))
+
+    sharded = build_mod.build(
+        "sharded_st", x, mesh=mesh, axis_names=("shard",), observer=observe
+    )
+    rows.append(("distributed_build_peak", peak))
+    rows.append(("sharded_steady", _max_device_bytes(sharded)))
+    return rows
 
 
 def run_build_mem():
     devices = [1, 2] if common.SMOKE else [1, 2, 4, 8]
     n = 1 << 16 if common.SMOKE else 1 << 20
-    for n_dev in devices:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
-        env["PYTHONPATH"] = "src:."
-        env["RMQ_BUILDMEM_N"] = str(n)
-        out = subprocess.run(
-            [sys.executable, "-c", _BUILD_MEM_CHILD],
-            env=env,
-            capture_output=True,
-            text=True,
-        )
-        if out.returncode != 0:
-            emit(f"build_mem/ndev={n_dev}", 0.0, "FAILED")
-            continue
-        for line in out.stdout.strip().splitlines():
-            kind, nbytes = line.split()
+    for n_dev, rows in common.per_device_count(
+        "benchmarks.memory_usage:measure_build_mem", devices, n=n
+    ):
+        for kind, nbytes in rows:
             emit(
                 f"build_mem/ndev={n_dev}/{kind}/n={n}",
                 0.0,

@@ -1,6 +1,7 @@
 """Replaces paper Fig. 14/15 (GPU-generation / SM scaling, not measurable in
 this container): scaling of the DISTRIBUTED RMQ engine with shard count,
-measured on fake CPU devices via a subprocess sweep.
+measured per device count (``common.per_device_count``: virtual CPU devices
+in a child process, or the chips this process holds).
 
 Reproduced claim analogue: the blocked engine's throughput scales with
 parallel resources (paper: RT cores/SMs; here: mesh shards), because the
@@ -9,54 +10,49 @@ query batch is embarrassingly parallel up to the two min all-reduces.
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from . import common
-from .common import emit
+from .common import emit, make_queries
 
-_CHILD = r"""
-import os, time, numpy as np, jax, jax.numpy as jnp
-from repro.core import distributed
-from repro.launch.mesh import make_mesh, set_mesh
-from benchmarks.common import make_queries
-n_dev = len(jax.devices())
-mesh = make_mesh((n_dev,), ("shard",))
-rng = np.random.default_rng(0)
-n = int(os.environ.get("RMQ_MESH_BENCH_N", 1 << 20))
-x = rng.random(n, dtype=np.float32)
-with set_mesh(mesh):
-    s = distributed.build_sharded(jnp.asarray(x), mesh, ("shard",), 1024)
-    qfn = distributed.make_query_fn(mesh, ("shard",))
-    l, r = make_queries(rng, n, 8192, "small")
-    lj, rj = jnp.asarray(l), jnp.asarray(r)
-    out = qfn(s, lj, rj); jax.block_until_ready(out)
-    t0 = time.perf_counter()
-    for _ in range(5):
+_BATCH = 8192
+
+
+def measure(devices, n: int) -> float:
+    """Seconds per small-range batch of the distributed engine on ``devices``."""
+    from repro.core import distributed
+    from repro.launch.mesh import make_group_mesh, set_mesh
+
+    mesh = make_group_mesh(devices)
+    rng = np.random.default_rng(0)
+    x = rng.random(n, dtype=np.float32)
+    with set_mesh(mesh):
+        s = distributed.build_sharded(jnp.asarray(x), mesh, ("shard",), 1024)
+        qfn = distributed.make_query_fn(mesh, ("shard",))
+        l, r = make_queries(rng, n, _BATCH, "small")
+        lj, rj = jnp.asarray(l), jnp.asarray(r)
         out = qfn(s, lj, rj)
-    jax.block_until_ready(out)
-    print((time.perf_counter() - t0) / 5)
-"""
+        jax.block_until_ready(out)
+        t0 = time.perf_counter()
+        for _ in range(5):
+            out = qfn(s, lj, rj)
+        jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / 5
 
 
 def run():
     devices = [1, 2] if common.SMOKE else [1, 2, 4, 8]
-    for n_dev in devices:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
-        env["PYTHONPATH"] = "src:."
-        if common.SMOKE:
-            env["RMQ_MESH_BENCH_N"] = str(1 << 16)
-        out = subprocess.run(
-            [sys.executable, "-c", _CHILD], env=env, capture_output=True, text=True
+    n = 1 << 16 if common.SMOKE else 1 << 20
+    for n_dev, t in common.per_device_count("benchmarks.mesh_scaling:measure", devices, n=n):
+        emit(
+            f"fig14/distributed-rmq/shards={n_dev}",
+            t / _BATCH,
+            f"{t/_BATCH*1e9:.1f}ns_per_rmq",
         )
-        if out.returncode != 0:
-            emit(f"fig14/shards={n_dev}", 0.0, "FAILED")
-            continue
-        t = float(out.stdout.strip().splitlines()[-1])
-        emit(f"fig14/distributed-rmq/shards={n_dev}", t / 8192, f"{t/8192*1e9:.1f}ns_per_rmq")
 
 
 if __name__ == "__main__":

@@ -68,6 +68,9 @@ def main() -> None:
         update_throughput,
     )
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     common.SMOKE = args.smoke
 
     suites = {

@@ -9,67 +9,61 @@ One batch-sharded-mode row per device count shows the replicated-structure /
 sharded-queries dual; one 2D-mode row (structure x batch mesh, squarest
 factoring) shows the product.
 
-Subprocess per device count (XLA fixes the device count at first jax import).
+One process per device count on the CPU backend (XLA fixes the virtual
+device count at first jax import); on a chip, one process over the devices
+it holds (``common.per_device_count``).
 """
 
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh
 
 from . import common
-from .common import emit
+from .common import emit, make_queries
 
 _BATCH = 8192
 
-_CHILD = r"""
-import os, time, numpy as np, jax, jax.numpy as jnp
-from repro.core import sharded_hybrid
-from repro.launch.mesh import factor_2d, make_mesh
-from benchmarks.common import make_queries
-n_dev = len(jax.devices())
-mesh = make_mesh((n_dev,), ("shard",))
-mesh2d = make_mesh(factor_2d(n_dev), ("struct", "qbatch"))
-rng = np.random.default_rng(0)
-n = int(os.environ["RMQ_SHYBRID_BENCH_N"])
-batch = int(os.environ["RMQ_SHYBRID_BENCH_B"])
-x = rng.random(n, dtype=np.float32)
-for mode in ("shard_structure", "shard_batch", "shard_2d"):
-    m, axes = (mesh2d, ("struct", "qbatch")) if mode == "shard_2d" else (mesh, ("shard",))
-    s = sharded_hybrid.build(jnp.asarray(x), m, axes, 1024, mode=mode)
-    dists = ("small", "medium", "large") if mode == "shard_structure" else ("medium",)
-    for dist in dists:
-        l, r = make_queries(rng, n, batch, dist)
-        out = sharded_hybrid.query(s, l, r)  # warmup / compile
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        for _ in range(5):
-            out = sharded_hybrid.query(s, l, r)
-        jax.block_until_ready(out)
-        print(f"{mode},{dist},{(time.perf_counter() - t0) / 5}")
-"""
+
+def measure(devices, n: int, batch: int):
+    """Seconds per batch: [(mode, dist, seconds)] over a mesh of ``devices``."""
+    from repro.core import sharded_hybrid
+    from repro.launch.mesh import factor_2d
+
+    devs = np.asarray(devices, dtype=object)
+    mesh = Mesh(devs, ("shard",))
+    mesh2d = Mesh(devs.reshape(factor_2d(len(devices))), ("struct", "qbatch"))
+    rng = np.random.default_rng(0)
+    x = rng.random(n, dtype=np.float32)
+    rows = []
+    for mode in ("shard_structure", "shard_batch", "shard_2d"):
+        m, axes = (mesh2d, ("struct", "qbatch")) if mode == "shard_2d" else (mesh, ("shard",))
+        s = sharded_hybrid.build(jnp.asarray(x), m, axes, 1024, mode=mode)
+        dists = ("small", "medium", "large") if mode == "shard_structure" else ("medium",)
+        for dist in dists:
+            l, r = make_queries(rng, n, batch, dist)
+            out = sharded_hybrid.query(s, l, r)  # warmup / compile
+            jax.block_until_ready(out)
+            t0 = time.perf_counter()
+            for _ in range(5):
+                out = sharded_hybrid.query(s, l, r)
+            jax.block_until_ready(out)
+            rows.append((mode, dist, (time.perf_counter() - t0) / 5))
+    return rows
 
 
 def run():
     devices = [1, 2] if common.SMOKE else [1, 2, 4, 8]
     n = 1 << 16 if common.SMOKE else 1 << 20
     batch = 2048 if common.SMOKE else _BATCH
-    for n_dev in devices:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
-        env["PYTHONPATH"] = "src:."
-        env["RMQ_SHYBRID_BENCH_N"] = str(n)
-        env["RMQ_SHYBRID_BENCH_B"] = str(batch)
-        out = subprocess.run(
-            [sys.executable, "-c", _CHILD], env=env, capture_output=True, text=True
-        )
-        if out.returncode != 0:
-            emit(f"sharded_hybrid/shards={n_dev}", 0.0, "FAILED")
-            continue
-        for line in out.stdout.strip().splitlines():
-            mode, dist, t = line.split(",")
-            t = float(t)
+    for n_dev, rows in common.per_device_count(
+        "benchmarks.sharded_hybrid:measure", devices, n=n, batch=batch
+    ):
+        for mode, dist, t in rows:
             tag = {"shard_batch": "qshard/", "shard_2d": "2d/"}.get(mode, "")
             emit(
                 f"sharded_hybrid/shards={n_dev}/{tag}dist={dist}",

@@ -31,6 +31,7 @@ wrappers over the same planners.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -541,23 +542,16 @@ def _plan_hybrid(
             else:
                 short_fn = lambda l, r: block_rmq.query_packed(blocked, spec, l, r)
             long_fn = lambda l, r: sparse_table.query_packed(table, spec, l, r)
-        elif use_kernels:
-            from repro import kernels
-
-            # jitted inside; closes over the tuned launch geometry
-            short_fn = lambda l, r: kernels.ops.query(blocked, l, r, config=cfg)
-            long_fn = None
         else:
-            short_fn = jax.jit(lambda l, r: block_rmq.query(blocked, l, r))
-            long_fn = None
+            if use_kernels:
+                from repro import kernels
 
-        if long_fn is None:
-
-            def _long(l, r):
-                idx = sparse_table.query(table, l, r)
-                return idx, x[idx]
-
-            long_fn = jax.jit(_long)
+                # The kernel call is jitted inside, with the structure as
+                # arguments; the tuned launch geometry rides as a static.
+                short_fn = functools.partial(kernels.ops.query, blocked, config=cfg)
+            else:
+                short_fn = functools.partial(hybrid.block_query, blocked)
+            long_fn = functools.partial(hybrid.long_query, table, x)
 
         state["result"] = hybrid.HybridRMQ(
             blocked=blocked,

@@ -43,29 +43,13 @@ the shard, never the full (K, n) table.
 from __future__ import annotations
 
 import functools
-from typing import Sequence, Tuple
+from typing import NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at top level with `check_vma`
-    from jax import shard_map as _shard_map_impl
-
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x: experimental module, kwarg named `check_rep`
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version-compat wrapper: maps ``check_vma`` to the installed jax's kwarg."""
-    kw = {_SHARD_MAP_CHECK_KW: check_vma}
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-from typing import NamedTuple
 
 from . import block_rmq, packing, sparse_table
 from .block_rmq import BlockRMQ, PackedBlockRMQ, maxval
@@ -109,17 +93,11 @@ def num_shards(mesh: Mesh, axis_names: Sequence[str]) -> int:
     return num
 
 
-def _axis_size(name: str):
-    if hasattr(jax.lax, "axis_size"):  # jax >= 0.5
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)  # folds to a constant inside shard_map
-
-
 def _flat_axis_index(axis_names: Sequence[str]) -> jax.Array:
     """Flattened linear device index across the given mesh axes."""
     idx = jnp.int32(0)
     for name in axis_names:
-        idx = idx * _axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx
 
 

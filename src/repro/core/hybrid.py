@@ -32,7 +32,7 @@ import numpy as np
 
 from repro.obs import trace as obs_trace
 
-from . import sparse_table
+from . import block_rmq, sparse_table
 from .block_rmq import BlockRMQ
 
 __all__ = [
@@ -99,6 +99,19 @@ def build(
         kernel_config=kernel_config,
         packed=packed,
     )
+
+
+def _long_query(table: sparse_table.SparseTable, x, l, r):
+    idx = sparse_table.query(table, l, r)
+    return idx, x[idx]
+
+
+# The pure-jnp paths, jitted once at module level and bound to a structure
+# with functools.partial: the structure is an argument, never a closed-over
+# constant (which would copy a multi-GiB table into every lowered program),
+# and a same-shape structure (a published update) is a jit-cache hit.
+long_query = jax.jit(_long_query)
+block_query = jax.jit(block_rmq.query)
 
 
 # Per-thread sink for regime-split observations: the serving layer wraps each

@@ -46,21 +46,36 @@ def _pick_left(x, a, b):
     return jnp.where(x[a] <= x[b], a, b)
 
 
+@jax.jit
 def build(x: jax.Array) -> SparseTable:
-    """Build the doubling table. Python loop over K<=32 levels (n is static)."""
+    """Build the doubling table: one jitted loop over the K levels.
+
+    Each level carries its window minima beside their indices, so a level is
+    a shift and a select — the same ``x[a] <= x[b]`` pick as ``_pick_left``
+    without gathering ``x`` — and writes its row into the preallocated table
+    in place. Peak memory is the table plus a few length-n rows, which is
+    what lets a TPU build the largest table its HBM holds.
+    """
     n = x.shape[0]
     k_levels = max(1, (n - 1).bit_length() + 1) if n > 1 else 1
     cur = jnp.arange(n, dtype=jnp.int32)
-    rows = [cur]
-    for k in range(1, k_levels):
-        h = 1 << (k - 1)
-        if h >= n:
-            rows.append(cur)
-            continue
-        shifted = jnp.concatenate([cur[h:], jnp.broadcast_to(cur[-1], (h,))])
-        cur = _pick_left(x, cur, shifted)
-        rows.append(cur)
-    return SparseTable(idx=jnp.stack(rows), x=x)
+    table = jnp.zeros((k_levels, n), jnp.int32).at[0].set(cur)
+
+    def shift(a, h):  # a[i + h], clamped to a[-1] past the end
+        ext = jnp.concatenate([a, jnp.broadcast_to(a[-1], (n,))])
+        return jax.lax.dynamic_slice(ext, (h,), (n,))
+
+    def level(k, carry):
+        table, cur, val = carry  # val == x[cur]
+        h = jnp.left_shift(jnp.int32(1), k - 1)  # < n for every level built
+        sv = shift(val, h)
+        take = val <= sv  # leftmost tie: prefer the left window
+        cur = jnp.where(take, cur, shift(cur, h))
+        val = jnp.where(take, val, sv)
+        return table.at[k].set(cur), cur, val
+
+    table, _, _ = jax.lax.fori_loop(1, k_levels, level, (table, cur, x))
+    return SparseTable(idx=table, x=x)
 
 
 def exact_log2(length: jax.Array) -> jax.Array:

@@ -100,7 +100,7 @@ class DurableEngine:
 
     @classmethod
     def restore(
-        cls, root: str, *, mesh=None, axis_names=None, fault=None
+        cls, root: str, *, mesh=None, axis_names=None, device=None, fault=None
     ) -> "DurableEngine":
         """Latest checkpoint + journal-suffix replay -> a consistent engine.
 
@@ -113,7 +113,9 @@ class DurableEngine:
         """
         ckpt = os.path.join(root, _CKPT_SUBDIR)
         arrays, meta, _ = checkpoint_mod.load_snapshot(ckpt)
-        online = OnlineEngine.from_snapshot(arrays, meta, mesh=mesh, axis_names=axis_names)
+        online = OnlineEngine.from_snapshot(
+            arrays, meta, mesh=mesh, axis_names=axis_names, device=device
+        )
         d = cls(online, root, fault=fault, _seq=int(meta["seq"]))
         tr = obs_trace.get_tracer()
         with tr.span("restore", attrs={"root": root} if tr.enabled else None):

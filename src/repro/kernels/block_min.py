@@ -20,6 +20,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.block_rmq import maxval
 
+from .tiling import resolve_interpret
+
 __all__ = ["block_min"]
 
 
@@ -37,8 +39,7 @@ def _kernel(x_ref, val_ref, idx_ref):
 @functools.partial(jax.jit, static_argnames=("tile_rows", "interpret"))
 def block_min(x_blocks: jax.Array, *, tile_rows: int = 8, interpret: bool | None = None):
     """Per-block (min value, leftmost local argmin). x_blocks: (nb, bs)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     nb, bs = x_blocks.shape
     pad = (-nb) % tile_rows
     if pad:

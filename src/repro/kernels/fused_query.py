@@ -5,24 +5,22 @@ partial, *and* the O(1) sparse-table interior candidate — emitting the final
 ``(idx, val)``. This collapses the previous three dispatches (partials
 kernel, XLA sparse-table gathers, XLA merge) into a single kernel launch.
 
-Grid: ``(B // tile, tile)``. The minor axis walks the queries of a tile; each
-minor step DMAs exactly the rows *that one query* needs via scalar-prefetch
-index maps and stages them into ``(tile, bs)`` VMEM scratch accumulators. At
-the last minor step the whole tile merges vectorized — one VPU masked min per
-partial side — and writes the revisited ``(tile, 1)`` output block. Compared
-to v1 (a 1D grid whose pallas_call repeated every operand ``tile`` times so
-each slot could carry its own index map), operand count is constant in
-``tile``: one operand per logical input, with the minor grid id selecting the
-per-query row. That keeps lowering time flat while the autotuner sweeps
-larger tiles.
+Grid: ``(B // tile,)``. Each step gathers the rows its ``tile`` queries need
+from HBM with one async DMA per query and row (``tiling.row_copies``) into
+``(tile, w)`` VMEM scratch, then answers the whole tile vectorized — one VPU
+masked min per partial side, one-hot lane picks for the interior cells —
+and writes its ``(tile, 1)`` output block. Operand count is constant in
+``tile``: one operand per logical input, the scalar-prefetch index vectors
+naming each query's rows.
 
 Two fetch strategies share the kernel body (``fetch=``):
 
   * ``"resident"`` — the per-block min arrays (``bmin_val``/``bmin_gidx``)
-    ride along as constant whole-array VMEM residents and the level-k
-    doubling-table row ``st.idx[k[q], :]`` is DMA'd per query. Per-step DMA
-    volume grows with nb (the row is ``(1, nb)``), which caps this path at
-    nb ~ 2^13 blocks.
+    ride along as constant whole-array VMEM residents. Each query DMAs the
+    two ``(1, 128)`` windows of the level-k doubling-table row
+    ``st.idx[k[q], :]`` that hold its cells, then hops through the resident
+    planes with a one-hot pick over all nb lanes. VMEM and that pick grow
+    with nb, which caps this path at nb ~ 2^13 blocks.
   * ``"dma"`` — nothing nb-sized touches VMEM. The doubling table is
     *value-augmented* at build time (``st_val[k, p] = bmin_val[st.idx[k, p]]``
     and ``st_gidx`` likewise, see :func:`interior_tables`), so the interior
@@ -30,6 +28,10 @@ Two fetch strategies share the kernel body (``fetch=``):
     ``(k, bpos)``. Each query DMAs four ``(1, 128)`` lane-aligned windows
     (value + gidx at each of the two positions) — bounded VMEM for
     arbitrarily large nb.
+
+A single-row DMA compiles only when the row is one 128-lane tile, so every
+table the kernel windows is viewed as ``(K, nbp // 128, 128)``
+(:func:`as_windows`), and the compiled kernels need ``block_size == 128``.
 
 ``fetch="auto"`` picks per the nb ceiling (``tuning.RESIDENT_NB_CEILING``).
 Both strategies are bit-identical to the oracle: the lo window starts at or
@@ -59,15 +61,18 @@ from repro.core.block_rmq import maxval
 from repro.core.sparse_table import exact_log2
 
 from .tiling import (
+    LANES,
+    lane_pick,
     pad_to_tiles,
+    resolve_interpret,
+    row_copies,
+    run_copies,
     scalar_col,
-    tiled2_out_specs,
-    tiled2_row_spec,
-    tiled2_window_spec,
+    tile_out_specs,
 )
-from .tuning import DEFAULT_TILE, RESIDENT_NB_CEILING, resolve_fetch
+from .tuning import DEFAULT_TILE, resolve_fetch
 
-__all__ = ["fused_query", "fused_query_packed", "interior_tables", "DEFAULT_TILE"]
+__all__ = ["as_windows", "fused_query", "fused_query_packed", "interior_tables", "DEFAULT_TILE"]
 
 _logger = logging.getLogger(__name__)
 
@@ -76,97 +81,149 @@ _logger = logging.getLogger(__name__)
 # would be silent exactly when the recompute recurs (same shapes re-trace).
 _warned_materialize = False
 
-# DMA window width: one lane-aligned VREG row per fetched table cell.
-_W = 128
+# DMA window width: one lane-aligned vreg row per fetched table cell.
+_W = LANES
 
-# Scalar-prefetch operand order (SMEM, available to index maps + kernel).
+# Scalar-prefetch operand order (SMEM: DMA addresses and per-query columns).
 _N_PREFETCH = 11  # bl, br, ls, le, re, k, ilo, bpos, hasint, wlo, whi
+
+_ANY = pl.BlockSpec(memory_space=pl.ANY)  # operand left in HBM, DMA'd by hand
+
+
+def _decompose(l, r, bs: int, nb: int):
+    """Host-side (XLA) per-query scalars, in ``_N_PREFETCH`` order."""
+    l = l.astype(jnp.int32)
+    r = r.astype(jnp.int32)
+    bl = l // bs
+    br = r // bs
+    ls = l - bl * bs
+    re = r - br * bs
+    le = jnp.where(bl == br, re, bs - 1)
+    hasint = ((br - bl) >= 2).astype(jnp.int32)
+    ilo = jnp.clip(bl + 1, 0, nb - 1)
+    ihi = jnp.maximum(jnp.clip(br - 1, 0, nb - 1), ilo)
+    k = exact_log2(ihi - ilo + 1)
+    bpos = ihi - jnp.left_shift(jnp.int32(1), k) + 1
+    wlo = ilo // _W  # lane-aligned window ids for the dma fetch strategy
+    whi = bpos // _W
+    return [bl, br, ls, le, re, k, ilo, bpos, hasint, wlo, whi]
+
+
+def block_rows(x_hbm, bl_ref, br_ref, q0, tile, xl_buf, xr_buf, sems):
+    """The DMAs staging each query's left and right partial-block rows."""
+    return row_copies(
+        [x_hbm.at[pl.ds(bl_ref[q0 + t], 1)] for t in range(tile)], xl_buf, sems.at[0]
+    ) + row_copies(
+        [x_hbm.at[pl.ds(br_ref[q0 + t], 1)] for t in range(tile)], xr_buf, sems.at[1]
+    )
+
+
+def _windows(t_hbm, k_ref, w_ref, q0, tile, buf, sem):
+    """The DMAs of each query's window ``t_hbm[k[q], w[q]]`` of a
+    ``(K, nbp // 128, 128)`` windowed table (:func:`as_windows`)."""
+    return row_copies(
+        [t_hbm.at[k_ref[q0 + t], pl.ds(w_ref[q0 + t], 1)] for t in range(tile)], buf, sem
+    )
+
+
+def as_windows(table: jax.Array, fill) -> jax.Array:
+    """A ``(K, nb)`` table padded with ``fill`` to ``nbp`` (a multiple of 128)
+    columns and viewed as ``(K, nbp // 128, 128)`` lane-aligned windows."""
+    k, nb = table.shape
+    nbp = -(-nb // _W) * _W
+    return jnp.pad(table, ((0, 0), (0, nbp - nb)), constant_values=fill).reshape(
+        k, nbp // _W, _W
+    )
+
+
+def partial_candidates(xl, xr, bl, br, ls, le, re, big):
+    """Left/right partial-block candidates of a tile, leftmost ties.
+
+    ``xl``/``xr`` are the ``(tile, bs)`` staged rows; the scalars are
+    ``(tile, 1)`` columns. Returns ``(pv, pi)`` columns.
+    """
+    bs = xl.shape[1]
+    big_i = jnp.int32(bs)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, xl.shape, 1)
+
+    ml = jnp.where((lanes >= ls) & (lanes <= le), xl, big)
+    lv = jnp.min(ml, axis=1, keepdims=True)
+    li = jnp.min(jnp.where(ml == lv, lanes, big_i), axis=1, keepdims=True)
+    lg = bl * bs + li
+
+    # Right partials (masked off for single-block queries).
+    mr = jnp.where(lanes <= re, xr, big)
+    rv = jnp.min(mr, axis=1, keepdims=True)
+    rv = jnp.where(br > bl, rv, big)
+    ri = jnp.min(jnp.where(mr == rv, lanes, big_i), axis=1, keepdims=True)
+    rg = br * bs + ri
+
+    take_l = lv <= rv  # left candidate has smaller indices: leftmost ties
+    return jnp.where(take_l, lv, rv), jnp.where(take_l, lg, rg)
+
+
+def _merge(pv, pi, iv, ii, bl, bs, val_ref, idx_ref):
+    """Final merge, exact leftmost: prefer the partial only when strictly
+    smaller, or tied with an index left of the interior block range."""
+    int_start = (bl + 1) * bs
+    prefer_partial = (pv < iv) | ((pv == iv) & (pi < int_start))
+    val_ref[...] = jnp.where(prefer_partial, pv, iv)
+    idx_ref[...] = jnp.where(prefer_partial, pi, ii)
 
 
 def _kernel(tile, fetch, *refs):
     (bl_ref, br_ref, ls_ref, le_ref, re_ref,
      k_ref, ilo_ref, bpos_ref, hasint_ref, wlo_ref, whi_ref) = refs[:_N_PREFETCH]
     body = refs[_N_PREFETCH:]
-    xl_ref, xr_ref = body[0], body[1]
+    q0 = pl.program_id(0) * tile
     if fetch == "resident":
-        st_ref, bv_ref, bg_ref = body[2:5]
-        val_ref, idx_ref = body[5:7]
-        xl_acc, xr_acc, iv_acc, ii_acc = body[7:11]
+        (x_hbm, st_hbm, bv_ref, bg_ref, val_ref, idx_ref,
+         xl_buf, xr_buf, lo_buf, hi_buf, sems) = body
+        copies = _windows(st_hbm, k_ref, wlo_ref, q0, tile, lo_buf, sems.at[2]) + _windows(
+            st_hbm, k_ref, whi_ref, q0, tile, hi_buf, sems.at[3]
+        )
     else:
-        lov_ref, hiv_ref, log_ref, hig_ref = body[2:6]
-        val_ref, idx_ref = body[6:8]
-        xl_acc, xr_acc, iv_acc, ii_acc = body[8:12]
+        (x_hbm, sv_hbm, sg_hbm, val_ref, idx_ref,
+         xl_buf, xr_buf, lov, hiv, log, hig, sems) = body
+        copies = (
+            _windows(sv_hbm, k_ref, wlo_ref, q0, tile, lov, sems.at[2])
+            + _windows(sv_hbm, k_ref, whi_ref, q0, tile, hiv, sems.at[3])
+            + _windows(sg_hbm, k_ref, wlo_ref, q0, tile, log, sems.at[4])
+            + _windows(sg_hbm, k_ref, whi_ref, q0, tile, hig, sems.at[5])
+        )
+    run_copies(block_rows(x_hbm, bl_ref, br_ref, q0, tile, xl_buf, xr_buf, sems) + copies)
 
-    i = pl.program_id(0)
-    t = pl.program_id(1)
-    q = i * tile + t
-    bs = xl_ref.shape[1]
-    big = maxval(xl_ref.dtype)
+    def col(ref):  # (tile, 1) column of per-query scalars from SMEM
+        return scalar_col(ref, q0, tile)
 
-    # Stage this query's partial-block rows into the tile accumulators.
-    xl_acc[pl.ds(t, 1)] = xl_ref[...]
-    xr_acc[pl.ds(t, 1)] = xr_ref[...]
+    bs = xl_buf.shape[1]
+    big = maxval(xl_buf.dtype)
+    big_i = jnp.iinfo(jnp.int32).max
+    off_lo = col(ilo_ref) - col(wlo_ref) * _W  # cell offsets inside the windows
+    off_hi = col(bpos_ref) - col(whi_ref) * _W
 
-    # This query's interior candidate -> SMEM slots; the merge step reads
-    # them back as a (tile,) vector.
+    # This tile's interior candidates: the two doubling-table cells per query.
     if fetch == "resident":
-        a = st_ref[0, ilo_ref[q]]
-        b = st_ref[0, bpos_ref[q]]
-        av = bv_ref[0, a]
-        bv = bv_ref[0, b]
-        ai = bg_ref[0, a]
-        bi = bg_ref[0, b]
+        a = lane_pick(lo_buf[...], off_lo, big_i)  # block ids, then the hop
+        b = lane_pick(hi_buf[...], off_hi, big_i)
+        bv, bg = bv_ref[...], bg_ref[...]
+        av, bv_ = lane_pick(bv, a, big), lane_pick(bv, b, big)
+        ai, bi = lane_pick(bg, a, big_i), lane_pick(bg, b, big_i)
     else:
-        off_lo = ilo_ref[q] - wlo_ref[q] * _W
-        off_hi = bpos_ref[q] - whi_ref[q] * _W
-        av = lov_ref[0, off_lo]
-        bv = hiv_ref[0, off_hi]
-        ai = log_ref[0, off_lo]
-        bi = hig_ref[0, off_hi]
+        av = lane_pick(lov[...], off_lo, big)
+        bv_ = lane_pick(hiv[...], off_hi, big)
+        ai = lane_pick(log[...], off_lo, big_i)
+        bi = lane_pick(hig[...], off_hi, big_i)
     # Leftmost tie: the lo cell covers [ilo, ilo+2^k) which starts at or
     # before the hi cell's [bpos, ihi], so prefer lo on equal values.
-    iv_acc[t] = jnp.where(hasint_ref[q] == 1, jnp.minimum(av, bv), big)
-    ii_acc[t] = jnp.where(av <= bv, ai, bi)
+    iv = jnp.where(col(hasint_ref) == 1, jnp.minimum(av, bv_), big)
+    ii = jnp.where(av <= bv_, ai, bi)
 
-    @pl.when(t == tile - 1)
-    def _merge():
-        big_i = jnp.int32(bs)
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (tile, bs), 1)
-        q0 = i * tile
-
-        def col(ref):  # (tile,) vector of per-query scalars from SMEM
-            return scalar_col(ref, q0, tile)
-
-        bl, br, ls, le, re = col(bl_ref), col(br_ref), col(ls_ref), col(le_ref), col(re_ref)
-
-        # Left partials, whole tile at once: (tile, bs) masked min + leftmost.
-        xl = xl_acc[...]
-        ml = jnp.where((lanes >= ls[:, None]) & (lanes <= le[:, None]), xl, big)
-        lv = jnp.min(ml, axis=1)
-        li = jnp.min(jnp.where(ml == lv[:, None], lanes, big_i), axis=1)
-        lg = bl * bs + li
-
-        # Right partials (masked off for single-block queries).
-        xr = xr_acc[...]
-        mr = jnp.where(lanes <= re[:, None], xr, big)
-        rv = jnp.min(mr, axis=1)
-        rv = jnp.where(br > bl, rv, big)
-        ri = jnp.min(jnp.where(mr == rv[:, None], lanes, big_i), axis=1)
-        rg = br * bs + ri
-
-        take_l = lv <= rv  # left candidate has smaller indices: leftmost ties
-        pv = jnp.where(take_l, lv, rv)
-        pi = jnp.where(take_l, lg, rg)
-
-        iv = scalar_col(iv_acc, 0, tile)
-        ii = scalar_col(ii_acc, 0, tile)
-
-        # Final merge, exact leftmost: prefer the partial only when strictly
-        # smaller, or tied with an index left of the interior block range.
-        int_start = (bl + 1) * bs
-        prefer_partial = (pv < iv) | ((pv == iv) & (pi < int_start))
-        val_ref[...] = jnp.where(prefer_partial, pv, iv)[:, None]
-        idx_ref[...] = jnp.where(prefer_partial, pi, ii)[:, None]
+    bl, br = col(bl_ref), col(br_ref)
+    pv, pi = partial_candidates(
+        xl_buf[...], xr_buf[...], bl, br, col(ls_ref), col(le_ref), col(re_ref), big
+    )
+    _merge(pv, pi, iv, ii, bl, bs, val_ref, idx_ref)
 
 
 def interior_tables(bmin_val: jax.Array, bmin_gidx: jax.Array, st_idx: jax.Array):
@@ -176,9 +233,13 @@ def interior_tables(bmin_val: jax.Array, bmin_gidx: jax.Array, st_idx: jax.Array
     the in-kernel interior lookup is two direct cell reads instead of an
     index hop through the resident block-min arrays. Computed once at build
     (XLA gathers are fine here — this is O(K * nb) build work, keeping the
-    per-query jaxpr gather-free).
+    per-query jaxpr gather-free) and returned in the kernel's windowed
+    ``(K, nbp // 128, 128)`` layout, so no query relays them out.
     """
-    return bmin_val[st_idx], bmin_gidx[st_idx]
+    return (
+        as_windows(bmin_val[st_idx], maxval(bmin_val.dtype)),
+        as_windows(bmin_gidx[st_idx], 0),
+    )
 
 
 @functools.partial(
@@ -192,8 +253,8 @@ def fused_query(
     l: jax.Array,  # (B,)
     r: jax.Array,  # (B,)
     *,
-    st_val: jax.Array | None = None,  # (K, nb) value-augmented table (dma)
-    st_gidx: jax.Array | None = None,  # (K, nb) int32 gidx-augmented table (dma)
+    st_val: jax.Array | None = None,  # value-augmented table (dma), windowed
+    st_gidx: jax.Array | None = None,  # gidx-augmented table (dma), windowed
     tile: int = DEFAULT_TILE,
     fetch: str = "auto",
     interpret: bool | None = None,
@@ -212,56 +273,31 @@ def fused_query(
     build stage owns the augmented tables and must notice losing them), and
     the default ``None`` derives but warns once per process.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     nb, bs = x_blocks.shape
+    interpret = resolve_interpret(interpret, tile, bs)
     b = l.shape[0]
     big = maxval(x_blocks.dtype)
     fetch = resolve_fetch(fetch, nb)
-    l = l.astype(jnp.int32)
-    r = r.astype(jnp.int32)
-
-    # Host-side (XLA) scalar decomposition — cheap int ops on (B,) vectors.
-    bl = l // bs
-    br = r // bs
-    ls = l - bl * bs
-    re = r - br * bs
-    le = jnp.where(bl == br, re, bs - 1)
-
-    hasint = ((br - bl) >= 2).astype(jnp.int32)
-    ilo = jnp.clip(bl + 1, 0, nb - 1)
-    ihi = jnp.maximum(jnp.clip(br - 1, 0, nb - 1), ilo)
-    k = exact_log2(ihi - ilo + 1)
-    bpos = ihi - jnp.left_shift(jnp.int32(1), k) + 1
-    wlo = ilo // _W  # lane-aligned window ids for the dma fetch strategy
-    whi = bpos // _W
 
     # Pad the batch to a whole number of tiles with trivial (0, 0) queries.
-    scalars = [bl, br, ls, le, re, k, ilo, bpos, hasint, wlo, whi]
-    scalars, bp = pad_to_tiles(scalars, b, tile)
+    scalars, bp = pad_to_tiles(_decompose(l, r, bs, nb), b, tile)
 
-    # Lane-align the per-block tables (last dim multiple of 128 for VMEM).
-    # Per-call cost note: when nb is already lane-aligned (every large-n
-    # config: nb = n/bs is a multiple of 128) the zero-width pads are elided
-    # by XLA; a misaligned nb implies a small nb, so the copy is sub-VREG
-    # noise. Keeping the pad here avoids widening the shared BlockRMQ pytree
-    # (whose field layout distributed.py's PartitionSpecs mirror).
-    nbp = -(-nb // _W) * _W
-    grid = (bp // tile, tile)
-    xl_spec = tiled2_row_spec((1, bs), 0, tile)  # x_blocks[bl[q]]
-    xr_spec = tiled2_row_spec((1, bs), 1, tile)  # x_blocks[br[q]]
+    row = pltpu.VMEM((tile, bs), x_blocks.dtype)  # staged partial-block rows
+    iwin = pltpu.VMEM((tile, _W), jnp.int32)  # one fetched table window per query
     if fetch == "resident":
+        # Lane-align the resident planes (last dim multiple of 128 for VMEM).
+        nbp = -(-nb // _W) * _W
         bv2 = jnp.pad(bmin_val, (0, nbp - nb), constant_values=big)[None, :]
         bg2 = jnp.pad(bmin_gidx, (0, nbp - nb))[None, :]
-        st2 = jnp.pad(st_idx, ((0, 0), (0, nbp - nb)))
         in_specs = [
-            xl_spec,
-            xr_spec,
-            tiled2_row_spec((1, nbp), 5, tile),  # st.idx[k[q], :]
-            pl.BlockSpec((1, nbp), lambda i, t, *s: (0, 0)),  # bmin_val (resident)
-            pl.BlockSpec((1, nbp), lambda i, t, *s: (0, 0)),  # bmin_gidx (resident)
+            _ANY,  # x_blocks: rows x_blocks[bl[q]], x_blocks[br[q]]
+            _ANY,  # st.idx windows
+            pl.BlockSpec((1, nbp), lambda i, *s: (0, 0)),  # bmin_val (resident)
+            pl.BlockSpec((1, nbp), lambda i, *s: (0, 0)),  # bmin_gidx (resident)
         ]
-        operands = (x_blocks, x_blocks, st2, bv2, bg2)
+        # At nb <= the resident ceiling this per-call relayout is < 1 MiB.
+        operands = (x_blocks, as_windows(st_idx, 0), bv2, bg2)
+        scratch = [row, row, iwin, iwin, pltpu.SemaphoreType.DMA((4,))]
     else:
         if st_val is None or st_gidx is None:
             if materialize_interior is False:
@@ -283,29 +319,19 @@ def fused_query(
                         "materialize_interior=True to opt in silently."
                     )
             st_val, st_gidx = interior_tables(bmin_val, bmin_gidx, st_idx)
-        sv2 = jnp.pad(st_val, ((0, 0), (0, nbp - nb)), constant_values=big)
-        sg2 = jnp.pad(st_gidx, ((0, 0), (0, nbp - nb)))
-        in_specs = [
-            xl_spec,
-            xr_spec,
-            tiled2_window_spec(_W, 5, 9, tile),  # st_val[k[q], ilo window]
-            tiled2_window_spec(_W, 5, 10, tile),  # st_val[k[q], bpos window]
-            tiled2_window_spec(_W, 5, 9, tile),  # st_gidx[k[q], ilo window]
-            tiled2_window_spec(_W, 5, 10, tile),  # st_gidx[k[q], bpos window]
-        ]
-        operands = (x_blocks, x_blocks, sv2, sv2, sg2, sg2)
+        elif st_val.ndim == 2:  # (K, nb) tables: relaid out to windows per call
+            st_val, st_gidx = as_windows(st_val, big), as_windows(st_gidx, 0)
+        in_specs = [_ANY, _ANY, _ANY]  # x_blocks rows; st_val, st_gidx windows
+        operands = (x_blocks, st_val, st_gidx)
+        vwin = pltpu.VMEM((tile, _W), x_blocks.dtype)
+        scratch = [row, row, vwin, vwin, iwin, iwin, pltpu.SemaphoreType.DMA((6,))]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=_N_PREFETCH,
-        grid=grid,
+        grid=(bp // tile,),
         in_specs=in_specs,
-        out_specs=tiled2_out_specs(tile),
-        scratch_shapes=[
-            pltpu.VMEM((tile, bs), x_blocks.dtype),  # xl accumulator
-            pltpu.VMEM((tile, bs), x_blocks.dtype),  # xr accumulator
-            pltpu.SMEM((tile,), x_blocks.dtype),  # interior values
-            pltpu.SMEM((tile,), jnp.int32),  # interior indices
-        ],
+        out_specs=tile_out_specs(tile),
+        scratch_shapes=scratch,
     )
     val, idx = pl.pallas_call(
         functools.partial(_kernel, tile, fetch),
@@ -328,11 +354,9 @@ def fused_query(
 #   * the partial-block scan is a plain masked word min — no equality
 #     rescan to recover the lane, the word IS (value, global index);
 #   * the interior candidate is two cells of the packed doubling table
-#     ``stw`` — the dma strategy fetches TWO (1, 128) windows per query
-#     where the unpacked kernel fetches FOUR (value + gidx at each
-#     position), and the resident strategy DMAs one (1, nb) ``stw`` row
-#     with NO resident planes at all (the unpacked kernel additionally
-#     parks ``bmin_val`` + ``bmin_gidx`` in VMEM);
+#     ``stw`` — TWO (1, 128) windows per query where the unpacked dma
+#     kernel fetches FOUR (value + gidx at each position), and NO resident
+#     planes at all, so packed32 has one kernel for either ``fetch``;
 #   * the final merge is ``min`` of three words — the leftmost-tie
 #     select chain is subsumed by word order, and the kernel emits one
 #     packed word per query that the host unpacks.
@@ -348,60 +372,36 @@ def fused_query(
 # layout serves through the XLA packed engines, never this kernel.
 
 
-def _kernel_packed(tile, fetch, idx_bits, pad, *refs):
+def _kernel_packed(tile, idx_bits, pad, *refs):
     """Exact-layout (packed32) kernel body: everything is int32 words."""
     (bl_ref, br_ref, ls_ref, le_ref, re_ref,
      k_ref, ilo_ref, bpos_ref, hasint_ref, wlo_ref, whi_ref) = refs[:_N_PREFETCH]
-    body = refs[_N_PREFETCH:]
-    xl_ref, xr_ref = body[0], body[1]
-    if fetch == "resident":
-        stw_ref = body[2]
-        word_ref = body[3]
-        xl_acc, xr_acc, iw_acc = body[4:7]
-    else:
-        lo_ref, hi_ref = body[2], body[3]
-        word_ref = body[4]
-        xl_acc, xr_acc, iw_acc = body[5:8]
+    x_hbm, stw_hbm, word_ref, xl_buf, xr_buf, lo_buf, hi_buf, sems = refs[_N_PREFETCH:]
+    q0 = pl.program_id(0) * tile
+    run_copies(
+        block_rows(x_hbm, bl_ref, br_ref, q0, tile, xl_buf, xr_buf, sems)
+        + _windows(stw_hbm, k_ref, wlo_ref, q0, tile, lo_buf, sems.at[2])
+        + _windows(stw_hbm, k_ref, whi_ref, q0, tile, hi_buf, sems.at[3])
+    )
 
-    i = pl.program_id(0)
-    t = pl.program_id(1)
-    q = i * tile + t
-    bs = xl_ref.shape[1]
+    def col(ref):
+        return scalar_col(ref, q0, tile)
 
-    xl_acc[pl.ds(t, 1)] = xl_ref[...]
-    xr_acc[pl.ds(t, 1)] = xr_ref[...]
+    wa = lane_pick(lo_buf[...], col(ilo_ref) - col(wlo_ref) * _W, pad)
+    wb = lane_pick(hi_buf[...], col(bpos_ref) - col(whi_ref) * _W, pad)
+    iw = jnp.where(col(hasint_ref) == 1, jnp.minimum(wa, wb), pad)
 
-    if fetch == "resident":
-        wa = stw_ref[0, ilo_ref[q]]
-        wb = stw_ref[0, bpos_ref[q]]
-    else:
-        wa = lo_ref[0, ilo_ref[q] - wlo_ref[q] * _W]
-        wb = hi_ref[0, bpos_ref[q] - whi_ref[q] * _W]
-    iw_acc[t] = jnp.where(hasint_ref[q] == 1, jnp.minimum(wa, wb), pad)
-
-    @pl.when(t == tile - 1)
-    def _merge():
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (tile, bs), 1)
-        q0 = i * tile
-
-        def col(ref):
-            return scalar_col(ref, q0, tile)
-
-        bl, br, ls, le, re = col(bl_ref), col(br_ref), col(ls_ref), col(le_ref), col(re_ref)
-
-        # Partials: one masked word min per side; the min word IS the
-        # leftmost argmin (pad words strictly dominate real ones).
-        lw = jnp.min(
-            jnp.where((lanes >= ls[:, None]) & (lanes <= le[:, None]), xl_acc[...], pad),
-            axis=1,
-        )
-        rw = jnp.min(jnp.where(lanes <= re[:, None], xr_acc[...], pad), axis=1)
-        rw = jnp.where(br > bl, rw, pad)
-
-        # Scratch is (tile,)-indexed from 0, unlike the (B,) prefetch refs
-        # ``col`` reads at q0 + t.
-        iw = scalar_col(iw_acc, 0, tile)
-        word_ref[...] = jnp.minimum(jnp.minimum(lw, rw), iw)[:, None]
+    bl, br = col(bl_ref), col(br_ref)
+    ls, le, re = col(ls_ref), col(le_ref), col(re_ref)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, xl_buf.shape, 1)
+    # Partials: one masked word min per side; the min word IS the
+    # leftmost argmin (pad words strictly dominate real ones).
+    lw = jnp.min(
+        jnp.where((lanes >= ls) & (lanes <= le), xl_buf[...], pad), axis=1, keepdims=True
+    )
+    rw = jnp.min(jnp.where(lanes <= re, xr_buf[...], pad), axis=1, keepdims=True)
+    rw = jnp.where(br > bl, rw, pad)
+    word_ref[...] = jnp.minimum(jnp.minimum(lw, rw), iw)
 
 
 def _kernel_quantized(tile, idx_bits, *refs):
@@ -409,69 +409,43 @@ def _kernel_quantized(tile, idx_bits, *refs):
     the exact fallback hop through the resident ``bmin_val`` plane."""
     (bl_ref, br_ref, ls_ref, le_ref, re_ref,
      k_ref, ilo_ref, bpos_ref, hasint_ref, wlo_ref, whi_ref) = refs[:_N_PREFETCH]
-    body = refs[_N_PREFETCH:]
-    xl_ref, xr_ref, stw_ref, bv_ref = body[0:4]
-    val_ref, idx_ref = body[4:6]
-    xl_acc, xr_acc, iv_acc, ii_acc = body[6:10]
+    (x_hbm, stw_hbm, bv_ref, val_ref, idx_ref,
+     xl_buf, xr_buf, lo_buf, hi_buf, sems) = refs[_N_PREFETCH:]
+    q0 = pl.program_id(0) * tile
+    run_copies(
+        block_rows(x_hbm, bl_ref, br_ref, q0, tile, xl_buf, xr_buf, sems)
+        + _windows(stw_hbm, k_ref, wlo_ref, q0, tile, lo_buf, sems.at[2])
+        + _windows(stw_hbm, k_ref, whi_ref, q0, tile, hi_buf, sems.at[3])
+    )
 
-    i = pl.program_id(0)
-    t = pl.program_id(1)
-    q = i * tile + t
-    bs = xl_ref.shape[1]
-    big = maxval(xl_ref.dtype)
+    def col(ref):
+        return scalar_col(ref, q0, tile)
+
+    bs = xl_buf.shape[1]
+    big = maxval(xl_buf.dtype)
+    big_i = jnp.iinfo(jnp.int32).max
     mask = (1 << idx_bits) - 1
 
-    xl_acc[pl.ds(t, 1)] = xl_ref[...]
-    xr_acc[pl.ds(t, 1)] = xr_ref[...]
-
-    wa = stw_ref[0, ilo_ref[q]]
-    wb = stw_ref[0, bpos_ref[q]]
+    wa = lane_pick(lo_buf[...], col(ilo_ref) - col(wlo_ref) * _W, big_i)
+    wb = lane_pick(hi_buf[...], col(bpos_ref) - col(whi_ref) * _W, big_i)
     ai = wa & mask
     bi = wb & mask
     # Exact values via the block hop: an interior cell's argmin is the min
     # of its own fully-covered block, so its exact value is that block's.
-    ava = bv_ref[0, ai // bs]
-    avb = bv_ref[0, bi // bs]
+    bv = bv_ref[...]
+    ava = lane_pick(bv, ai // bs, big)
+    avb = lane_pick(bv, bi // bs, big)
     collide = (wa >> idx_bits) == (wb >> idx_bits)
-    take_a = jnp.where(collide, ava <= avb, wa <= wb)
-    iv_acc[t] = jnp.where(hasint_ref[q] == 1, jnp.where(take_a, ava, avb), big)
-    ii_acc[t] = jnp.where(take_a, ai, bi)
+    # (A select between two boolean vectors does not lower on the TPU.)
+    take_a = (collide & (ava <= avb)) | (~collide & (wa <= wb))
+    iv = jnp.where(col(hasint_ref) == 1, jnp.where(take_a, ava, avb), big)
+    ii = jnp.where(take_a, ai, bi)
 
-    @pl.when(t == tile - 1)
-    def _merge():
-        big_i = jnp.int32(bs)
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (tile, bs), 1)
-        q0 = i * tile
-
-        def col(ref):
-            return scalar_col(ref, q0, tile)
-
-        bl, br, ls, le, re = col(bl_ref), col(br_ref), col(ls_ref), col(le_ref), col(re_ref)
-
-        xl = xl_acc[...]
-        ml = jnp.where((lanes >= ls[:, None]) & (lanes <= le[:, None]), xl, big)
-        lv = jnp.min(ml, axis=1)
-        li = jnp.min(jnp.where(ml == lv[:, None], lanes, big_i), axis=1)
-        lg = bl * bs + li
-
-        xr = xr_acc[...]
-        mr = jnp.where(lanes <= re[:, None], xr, big)
-        rv = jnp.min(mr, axis=1)
-        rv = jnp.where(br > bl, rv, big)
-        ri = jnp.min(jnp.where(mr == rv[:, None], lanes, big_i), axis=1)
-        rg = br * bs + ri
-
-        take_l = lv <= rv
-        pv = jnp.where(take_l, lv, rv)
-        pi = jnp.where(take_l, lg, rg)
-
-        iv = scalar_col(iv_acc, 0, tile)
-        ii = scalar_col(ii_acc, 0, tile)
-
-        int_start = (bl + 1) * bs
-        prefer_partial = (pv < iv) | ((pv == iv) & (pi < int_start))
-        val_ref[...] = jnp.where(prefer_partial, pv, iv)[:, None]
-        idx_ref[...] = jnp.where(prefer_partial, pi, ii)[:, None]
+    bl, br = col(bl_ref), col(br_ref)
+    pv, pi = partial_candidates(
+        xl_buf[...], xr_buf[...], bl, br, col(ls_ref), col(le_ref), col(re_ref), big
+    )
+    _merge(pv, pi, iv, ii, bl, bs, val_ref, idx_ref)
 
 
 @functools.partial(
@@ -493,11 +467,12 @@ def fused_query_packed(
 
     One kernel dispatch per batch over single-plane packed structures (see
     the section comment above for the per-layout fetch volumes). Layouts:
-    packed32 (exact; both fetch strategies) and quantized (resident only).
-    packed64 raises — int64 words have no TPU kernel path.
+    packed32 (exact; one kernel for either ``fetch``, which is validated)
+    and quantized (resident only). packed64 raises — int64 words have no
+    TPU kernel path.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    nb, bs = blocks.shape
+    interpret = resolve_interpret(interpret, tile, bs)
     if spec.layout == "packed64":
         raise ValueError(
             "packed64 words are int64 and have no TPU kernel path; "
@@ -505,58 +480,35 @@ def fused_query_packed(
         )
     if spec.layout not in ("packed32", "quantized"):
         raise ValueError(f"fused_query_packed wants packed32|quantized, got {spec.layout!r}")
-    nb, bs = blocks.shape
     b = l.shape[0]
-    fetch = resolve_fetch(fetch, nb)
+    resolve_fetch(fetch, nb)
+    pad = packing.pad_word(spec)
+
+    scalars, bp = pad_to_tiles(_decompose(l, r, bs, nb), b, tile)
+
+    row = pltpu.VMEM((tile, bs), blocks.dtype)
+    win = pltpu.VMEM((tile, _W), jnp.int32)
+    scratch = [row, row, win, win, pltpu.SemaphoreType.DMA((4,))]
+    # The packed doubling table is built (K, nb); this per-call relayout to
+    # windows costs one pass over it.
+    stw3 = as_windows(stw, pad)
+
     if spec.layout == "quantized":
         if bmin_val is None:
             raise ValueError("quantized fused_query_packed needs the bmin_val plane")
-        fetch = "resident"  # the exact-fallback hop lives in the resident plane
-    pad = packing.pad_word(spec)
-    l = l.astype(jnp.int32)
-    r = r.astype(jnp.int32)
-
-    bl = l // bs
-    br = r // bs
-    ls = l - bl * bs
-    re = r - br * bs
-    le = jnp.where(bl == br, re, bs - 1)
-
-    hasint = ((br - bl) >= 2).astype(jnp.int32)
-    ilo = jnp.clip(bl + 1, 0, nb - 1)
-    ihi = jnp.maximum(jnp.clip(br - 1, 0, nb - 1), ilo)
-    k = exact_log2(ihi - ilo + 1)
-    bpos = ihi - jnp.left_shift(jnp.int32(1), k) + 1
-    wlo = ilo // _W
-    whi = bpos // _W
-
-    scalars = [bl, br, ls, le, re, k, ilo, bpos, hasint, wlo, whi]
-    scalars, bp = pad_to_tiles(scalars, b, tile)
-
-    nbp = -(-nb // _W) * _W
-    grid = (bp // tile, tile)
-    xl_spec = tiled2_row_spec((1, bs), 0, tile)
-    xr_spec = tiled2_row_spec((1, bs), 1, tile)
-    stw2 = jnp.pad(stw, ((0, 0), (0, nbp - nb)), constant_values=pad)
-
-    if spec.layout == "quantized":
+        # The exact-fallback hop lives in this resident plane: no dma strategy.
+        nbp = -(-nb // _W) * _W
         bv2 = jnp.pad(bmin_val, (0, nbp - nb), constant_values=maxval(blocks.dtype))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=_N_PREFETCH,
-            grid=grid,
+            grid=(bp // tile,),
             in_specs=[
-                xl_spec,
-                xr_spec,
-                tiled2_row_spec((1, nbp), 5, tile),  # stw[k[q], :]
-                pl.BlockSpec((1, nbp), lambda i, t, *s: (0, 0)),  # bmin_val
+                _ANY,  # raw blocks: rows blocks[bl[q]], blocks[br[q]]
+                _ANY,  # stw windows
+                pl.BlockSpec((1, nbp), lambda i, *s: (0, 0)),  # bmin_val
             ],
-            out_specs=tiled2_out_specs(tile),
-            scratch_shapes=[
-                pltpu.VMEM((tile, bs), blocks.dtype),
-                pltpu.VMEM((tile, bs), blocks.dtype),
-                pltpu.SMEM((tile,), blocks.dtype),
-                pltpu.SMEM((tile,), jnp.int32),
-            ],
+            out_specs=tile_out_specs(tile),
+            scratch_shapes=scratch,
         )
         val, idx = pl.pallas_call(
             functools.partial(_kernel_quantized, tile, spec.idx_bits),
@@ -566,41 +518,21 @@ def fused_query_packed(
                 jax.ShapeDtypeStruct((bp, 1), jnp.int32),
             ],
             interpret=interpret,
-        )(*scalars, blocks, blocks, stw2, bv2[None, :])
+        )(*scalars, blocks, stw3, bv2[None, :])
         return idx[:b, 0], val[:b, 0]
-
-    if fetch == "resident":
-        in_specs = [
-            xl_spec,
-            xr_spec,
-            tiled2_row_spec((1, nbp), 5, tile),  # stw[k[q], :] — sole table fetch
-        ]
-        operands = (blocks, blocks, stw2)
-    else:
-        in_specs = [
-            xl_spec,
-            xr_spec,
-            tiled2_window_spec(_W, 5, 9, tile),  # stw[k[q], ilo window]
-            tiled2_window_spec(_W, 5, 10, tile),  # stw[k[q], bpos window]
-        ]
-        operands = (blocks, blocks, stw2, stw2)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=_N_PREFETCH,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((tile, 1), lambda i, t, *s: (i, 0))],
-        scratch_shapes=[
-            pltpu.VMEM((tile, bs), jnp.int32),  # xl word accumulator
-            pltpu.VMEM((tile, bs), jnp.int32),  # xr word accumulator
-            pltpu.SMEM((tile,), jnp.int32),  # interior words
-        ],
+        grid=(bp // tile,),
+        in_specs=[_ANY, _ANY],  # word block rows; stw windows
+        out_specs=tile_out_specs(tile, 1),
+        scratch_shapes=scratch,
     )
     (word,) = pl.pallas_call(
-        functools.partial(_kernel_packed, tile, fetch, spec.idx_bits, pad),
+        functools.partial(_kernel_packed, tile, spec.idx_bits, pad),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((bp, 1), jnp.int32)],
         interpret=interpret,
-    )(*scalars, *operands)
+    )(*scalars, blocks, stw3)
     w = word[:b, 0]
     return packing.unpack_idx(spec, w), packing.unpack_val(spec, w)

@@ -57,8 +57,8 @@ class FusedRMQ(NamedTuple):
     bmin_val: jax.Array  # (nb,)
     bmin_gidx: jax.Array  # (nb,) int32
     st: sparse_table.SparseTable  # doubling table over bmin_val
-    st_val: jax.Array  # (K, nb): bmin_val[st.idx] (DMA fetch strategy)
-    st_gidx: jax.Array  # (K, nb) int32: bmin_gidx[st.idx]
+    st_val: jax.Array  # bmin_val[st.idx], windowed (K, nbp // 128, 128) (dma)
+    st_gidx: jax.Array  # bmin_gidx[st.idx] int32, windowed likewise
 
 
 def build(x: jax.Array, block_size: int, *, interpret: bool | None = None) -> FusedRMQ:

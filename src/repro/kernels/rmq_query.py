@@ -1,17 +1,17 @@
 """Pallas TPU kernel: fused partial-block RMQ scans (query phase, level 1).
 
 The RT-core analogue: ``tile`` queries per grid step ("a warp of rays"), with
-each query's two candidate blocks streamed HBM->VMEM by the pipeline. Scalar
-prefetch (SMEM) carries per-query block ids so the BlockSpec index_map can
-select *data-dependent* blocks — the TPU-idiomatic replacement for the BVH
-descent picking which leaf a ray visits: instead of a pointer walk, the DMA
-engine is programmed with the block id while the previous tile computes.
+each query's two candidate blocks DMA'd HBM->VMEM by hand. Scalar prefetch
+(SMEM) carries per-query block ids so the kernel can gather *data-dependent*
+blocks — the TPU-idiomatic replacement for the BVH descent picking which leaf
+a ray visits: instead of a pointer walk, the DMA engine is programmed with
+the block id.
 
 Both partial scans (left tail, right head) are fused into one kernel, and the
-grid is tiled ``(B // tile,)``: each step concatenates its ``tile`` left rows
-and ``tile`` right rows into ``(tile, bs)`` VMEM tiles so the VPU does two
+grid is tiled ``(B // tile,)``: each step gathers its ``tile`` left rows and
+``tile`` right rows into ``(tile, bs)`` VMEM scratch so the VPU does two
 masked mins for the whole tile instead of per query, amortizing DMA issue and
-grid overhead. ``tile=1`` reproduces the original one-ray-per-step layout.
+grid overhead.
 
 For the fully fused path (interior sparse-table candidate + final merge in
 the same dispatch) see ``fused_query.py``.
@@ -28,48 +28,27 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.block_rmq import maxval
 
-from .tiling import pad_to_tiles, row_spec, scalar_col, tile_out_specs
+from .fused_query import block_rows, partial_candidates
+from .tiling import pad_to_tiles, resolve_interpret, run_copies, scalar_col, tile_out_specs
 from .tuning import DEFAULT_TILE
 
 __all__ = ["rmq_partials", "DEFAULT_TILE"]
 
 
-
-def _kernel(tile, bl_ref, br_ref, ls_ref, le_ref, re_ref, *refs):
-    xl_refs = refs[0:tile]
-    xr_refs = refs[tile : 2 * tile]
-    val_ref, idx_ref = refs[2 * tile], refs[2 * tile + 1]
-
-    i = pl.program_id(0)
-    q0 = i * tile
-    bs = xl_refs[0].shape[1]
-    big = maxval(xl_refs[0].dtype)
-    big_i = jnp.int32(bs)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (tile, bs), 1)
+def _kernel(tile, bl_ref, br_ref, ls_ref, le_ref, re_ref, x_hbm, val_ref, idx_ref,
+            xl_buf, xr_buf, sems):
+    q0 = pl.program_id(0) * tile
+    run_copies(block_rows(x_hbm, bl_ref, br_ref, q0, tile, xl_buf, xr_buf, sems))
 
     def col(ref):
         return scalar_col(ref, q0, tile)
 
-    bl, br, ls, le, re = col(bl_ref), col(br_ref), col(ls_ref), col(le_ref), col(re_ref)
-
-    # Left partials: x[bl, ls:le+1] (non-empty by construction), whole tile.
-    xl = jnp.concatenate([r[...] for r in xl_refs], axis=0)
-    ml = jnp.where((lanes >= ls[:, None]) & (lanes <= le[:, None]), xl, big)
-    lv = jnp.min(ml, axis=1)
-    li = jnp.min(jnp.where(ml == lv[:, None], lanes, big_i), axis=1)
-    lg = bl * bs + li
-
-    # Right partials: x[br, 0:re+1], masked off for single-block queries.
-    xr = jnp.concatenate([r[...] for r in xr_refs], axis=0)
-    mr = jnp.where(lanes <= re[:, None], xr, big)
-    rv = jnp.min(mr, axis=1)
-    rv = jnp.where(br > bl, rv, big)
-    ri = jnp.min(jnp.where(mr == rv[:, None], lanes, big_i), axis=1)
-    rg = br * bs + ri
-
-    take_l = lv <= rv  # left candidate has smaller indices: leftmost ties
-    val_ref[...] = jnp.where(take_l, lv, rv)[:, None]
-    idx_ref[...] = jnp.where(take_l, lg, rg)[:, None]
+    pv, pi = partial_candidates(
+        xl_buf[...], xr_buf[...], col(bl_ref), col(br_ref), col(ls_ref),
+        col(le_ref), col(re_ref), maxval(xl_buf.dtype),
+    )
+    val_ref[...] = pv
+    idx_ref[...] = pi
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -85,24 +64,22 @@ def rmq_partials(
     interpret: bool | None = None,
 ):
     """Fused partial-block candidates. Returns (value (B,), global idx (B,))."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b = bl.shape[0]
     _, bs = x_blocks.shape
+    interpret = resolve_interpret(interpret, tile, bs)
+    b = bl.shape[0]
     args = [a.astype(jnp.int32) for a in (bl, br, lstart, lend, rend)]
 
     # Pad the batch to a whole number of tiles with trivial block-0 queries.
     args, bp = pad_to_tiles(args, b, tile)
 
+    row = pltpu.VMEM((tile, bs), x_blocks.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(bp // tile,),
-        in_specs=(
-            # data-dependent rows: x_blocks[bl[q]] then x_blocks[br[q]]
-            [row_spec((1, bs), 0, t, tile) for t in range(tile)]
-            + [row_spec((1, bs), 1, t, tile) for t in range(tile)]
-        ),
+        # data-dependent rows x_blocks[bl[q]] and x_blocks[br[q]], DMA'd by hand
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=tile_out_specs(tile),
+        scratch_shapes=[row, row, pltpu.SemaphoreType.DMA((2,))],
     )
     val, idx = pl.pallas_call(
         functools.partial(_kernel, tile),
@@ -112,5 +89,5 @@ def rmq_partials(
             jax.ShapeDtypeStruct((bp, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(*args, *([x_blocks] * (2 * tile)))
+    )(*args, x_blocks)
     return val[:b, 0], idx[:b, 0]

@@ -1,33 +1,72 @@
-"""Shared scaffolding for the tiled query kernels.
+"""Shared scaffolding for the Pallas kernels.
 
-Two grid generations coexist here:
+The query kernels run a 1-D grid ``(B // tile,)``: each step answers
+``tile`` queries. The data-dependent rows a query needs (a block of ``x``,
+or a 128-lane window of a doubling-table row) stay in HBM
+(``memory_space=pl.ANY``) and are gathered by hand — one async DMA per
+query into row ``t`` of a ``(tile, w)`` VMEM scratch, all started before any
+is waited on. A BlockSpec cannot express that fetch on a TPU: a ``(1, w)``
+block breaks the (8, 128) tiling rule. Per-query scalars and single-cell
+picks are then vectorised over the tile as ``(tile, 1)`` columns, because
+Mosaic cannot read one lane at a dynamic offset.
 
-* v1 — a 1D grid ``(B // tile,)`` where every data-dependent row needs its
-  own pallas_call operand slot, so callers repeat each operand ``tile``
-  times, one ``row_spec`` (with its ``t=t`` default-arg closure capture) per
-  slot. ``rmq_query`` and ``lane_query`` still use this idiom.
-* v2 — a 2D grid ``(B // tile, tile)`` whose minor axis walks the queries of
-  a tile, so ONE operand with a ``tiled2_*`` index map serves every slot and
-  dispatch arg count stays constant in ``tile``. ``fused_query`` uses this
-  (see its module docstring for the scratch-accumulator merge protocol).
-
-The batch padding and SMEM scalar stacking are shared by both.
+The batch padding, SMEM scalar columns and lane picks are shared by every
+query kernel.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
+    "LANES",
+    "SUBLANES",
+    "lane_pick",
     "pad_to_tiles",
-    "row_spec",
+    "resolve_interpret",
+    "row_copies",
+    "run_copies",
     "scalar_col",
     "tile_out_specs",
-    "tiled2_out_specs",
-    "tiled2_row_spec",
-    "tiled2_window_spec",
 ]
+
+# A (tile, 1) output block meets the TPU's (8, 128) tiling rule only when
+# tile is a multiple of the 8 sublanes of a vreg.
+SUBLANES = 8
+LANES = 128
+
+
+def resolve_interpret(
+    interpret: bool | None, tile: int | None = None, row: int | None = None
+) -> bool:
+    """Whether a Pallas kernel runs in interpret mode.
+
+    ``None`` means: compiled on a TPU, interpreted on the CPU backend (the
+    test and development path). Any other backend raises rather than
+    quietly interpreting on a device. A compiled launch also needs ``tile``
+    (when given) to be a whole number of sublanes, and each DMA'd ``row``
+    (when given) to be exactly one 128-lane tile: Mosaic refuses a one-row
+    slice of a wider array.
+    """
+    if interpret is None:
+        backend = jax.default_backend()
+        if backend == "tpu":
+            interpret = False
+        elif backend == "cpu":
+            interpret = True
+        else:
+            raise RuntimeError(
+                f"the Pallas kernels compile for a TPU or interpret on the CPU; "
+                f"backend {backend!r} is neither"
+            )
+    if not interpret and tile is not None and tile % SUBLANES:
+        raise ValueError(f"a compiled launch needs tile % {SUBLANES} == 0, got tile={tile}")
+    if not interpret and row is not None and row != LANES:
+        raise ValueError(f"a compiled launch DMAs {LANES}-lane rows, got rows of {row}")
+    return bool(interpret)
 
 
 def pad_to_tiles(args, b: int, tile: int):
@@ -42,61 +81,45 @@ def pad_to_tiles(args, b: int, tile: int):
     return args, bp
 
 
-def row_spec(block_shape, sel: int, t: int, tile: int) -> pl.BlockSpec:
-    """BlockSpec fetching one data-dependent row per query.
-
-    ``sel`` picks which scalar-prefetch operand carries the row id; ``t`` is
-    the query's slot within the tile. The defaults pin the loop variables at
-    definition time (the classic late-binding closure trap).
-    """
-    return pl.BlockSpec(
-        block_shape, lambda i, *s, t=t, sel=sel: (s[sel][i * tile + t], 0)
-    )
-
-
 def scalar_col(ref, q0, tile: int):
-    """Stack a tile's per-query scalars from an SMEM prefetch ref: (tile,)."""
-    return jnp.stack([ref[q0 + t] for t in range(tile)])
+    """A tile's per-query scalars from an SMEM ref as a ``(tile, 1)`` column."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+    out = jnp.zeros((tile, 1), ref.dtype)
+    for t in range(tile):
+        out = jnp.where(rows == t, ref[q0 + t], out)
+    return out
 
 
-def tile_out_specs(tile: int):
-    """The two (tile, 1) outputs (value, index) every query kernel emits."""
+def lane_pick(rows, pos, fill):
+    """``rows[t, pos[t]]`` per tile row, as a ``(tile, 1)`` column.
+
+    A one-hot masked min over the lanes: exactly one lane matches, so the
+    min is that cell's value, whatever ``fill`` is. ``rows`` is ``(tile, w)``
+    or a ``(1, w)`` plane shared by every row; ``pos`` is ``(tile, 1)``.
+    """
+    shape = (pos.shape[0], rows.shape[1])
+    lanes = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    picked = jnp.where(lanes == pos, jnp.broadcast_to(rows, shape), fill)
+    return jnp.min(picked, axis=1, keepdims=True)
+
+
+def row_copies(srcs, dst, sem):
+    """One DMA per tile slot: HBM view ``srcs[t]`` (shape ``(1, w)``) into
+    row ``t`` of the ``(tile, w)`` VMEM scratch ``dst``."""
     return [
-        pl.BlockSpec((tile, 1), lambda i, *s: (i, 0)),
-        pl.BlockSpec((tile, 1), lambda i, *s: (i, 0)),
+        pltpu.make_async_copy(src, dst.at[pl.ds(t, 1)], sem)
+        for t, src in enumerate(srcs)
     ]
 
 
-def tiled2_row_spec(block_shape, sel: int, tile: int) -> pl.BlockSpec:
-    """2D-grid BlockSpec fetching one data-dependent row per minor step.
-
-    The minor grid id ``t`` selects the query within the tile, so a single
-    operand serves all tile slots: row id = ``prefetch[sel][i * tile + t]``.
-    """
-    return pl.BlockSpec(
-        block_shape, lambda i, t, *s, sel=sel: (s[sel][i * tile + t], 0)
-    )
+def run_copies(copies) -> None:
+    """Start every copy, then wait for all: the row fetches overlap."""
+    for c in copies:
+        c.start()
+    for c in copies:
+        c.wait()
 
 
-def tiled2_window_spec(w: int, rsel: int, wsel: int, tile: int) -> pl.BlockSpec:
-    """2D-grid BlockSpec fetching a (1, w) window of a 2D table per minor step.
-
-    Row id from ``prefetch[rsel]``, window (column-block) id from
-    ``prefetch[wsel]`` — both indexed by the query slot ``i * tile + t``. The
-    window id is in block coordinates: element offset = id * w.
-    """
-    return pl.BlockSpec(
-        (1, w),
-        lambda i, t, *s, rsel=rsel, wsel=wsel: (
-            s[rsel][i * tile + t],
-            s[wsel][i * tile + t],
-        ),
-    )
-
-
-def tiled2_out_specs(tile: int):
-    """The two (tile, 1) outputs on the 2D grid (block revisited across t)."""
-    return [
-        pl.BlockSpec((tile, 1), lambda i, t, *s: (i, 0)),
-        pl.BlockSpec((tile, 1), lambda i, t, *s: (i, 0)),
-    ]
+def tile_out_specs(tile: int, n: int = 2):
+    """The ``(tile, 1)`` output blocks (value, index, ...) of a query kernel."""
+    return [pl.BlockSpec((tile, 1), lambda i, *s: (i, 0)) for _ in range(n)]

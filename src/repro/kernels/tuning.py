@@ -56,15 +56,17 @@ DEFAULT_TILE = 8
 # Table fetch strategies fused_query implements (module docstring there).
 FETCH_STRATEGIES = ("resident", "dma")
 
-# Above this many blocks the resident strategy's per-step (1, nb) doubling
-# row DMA (plus the resident bmin planes) stops fitting the VMEM budget;
-# "auto" switches to the bounded-VMEM dma strategy. See DESIGN.md §12.
+# Above this many blocks the resident strategy's VMEM-resident bmin planes
+# and its one-hot hop over all nb lanes per query stop paying; "auto"
+# switches to the bounded-VMEM dma strategy. See DESIGN.md §12.
 RESIDENT_NB_CEILING = 1 << 13
 
 # Swept values. Small on purpose: each candidate costs a build + timed
 # queries, and the product is per (n, batch, backend, ndev) cache entry.
-TUNE_TILES = (4, 8, 16)
-TUNE_BLOCK_SIZES = (128, 256)
+# Compiled kernels need a tile of whole sublanes (8) and a block of exactly
+# one 128-lane row (``tiling.resolve_interpret``).
+TUNE_TILES = (8, 16, 32)
+TUNE_BLOCK_SIZES = (128,)
 DEFAULT_TUNE_BATCH = 4096
 
 # The packed-structure layout axis (DESIGN.md §13). ``candidate_configs``

@@ -9,11 +9,7 @@ sees the full placeholder fleet.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: no AxisType; make_mesh takes no axis_types
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = [
     "factor_2d",
@@ -37,9 +33,7 @@ def factor_2d(ndev: int):
 
 
 def _mk(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -68,8 +62,5 @@ def make_group_mesh(devices, axes=("shard",)):
 
 
 def set_mesh(mesh):
-    """Context manager activating ``mesh`` (jax.set_mesh, or the Mesh itself
-    on jax 0.4.x where Mesh is the context manager)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager activating ``mesh`` (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)

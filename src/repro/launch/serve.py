@@ -51,10 +51,11 @@ import numpy as np
 from repro import update as update_mod
 from repro.core import build as build_mod
 from repro.core import ref, registry
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import factor_2d, make_mesh, set_mesh
 from repro.obs import Tracer, default_registry, set_tracer, verify_request_chains
 from repro.serve import RMQServer, ServeConfig, ServerOverloaded
-from repro.serve.workload import make_queries, run_poisson_clients
+from repro.serve.workload import DISTS, make_queries, run_poisson_clients
 
 __all__ = ["main"]
 
@@ -71,7 +72,7 @@ def _parser() -> argparse.ArgumentParser:
     )
     ap.add_argument("--mode", choices=["oneshot", "async"], default="oneshot")
     ap.add_argument("--n", type=int, default=1 << 20)
-    ap.add_argument("--dist", choices=["large", "medium", "small"], default="small")
+    ap.add_argument("--dist", choices=list(DISTS), default="small")
     ap.add_argument("--engine", choices=engines, default="sharded_hybrid")
     ap.add_argument(
         "--block-size",
@@ -326,7 +327,7 @@ def _run_oneshot(args, spec, state, x, rng) -> bool:
     return bool(ok)
 
 
-def _run_async(args, spec, state, x, plan, online=None) -> bool:
+def _run_async(args, spec, state, x, plan, online=None):
     cfg = ServeConfig(
         deadline_s=args.deadline_ms * 1e-3,
         max_batch=args.max_batch,
@@ -441,10 +442,10 @@ def _run_async(args, spec, state, x, plan, online=None) -> bool:
     ok = mismatches == 0 and served > 0
     if args.mutate:
         ok = ok and len(upd_futs) > 0
-    return ok
+    return ok, [st]
 
 
-def _run_fleet(args, spec, x) -> bool:
+def _run_fleet(args, spec, x):
     """Serve through a replica fleet (serve.fleet): regime-routed front door,
     bounded-lag rollouts, per-version oracle verification — the multi-replica
     twin of ``_run_async``."""
@@ -475,6 +476,12 @@ def _run_fleet(args, spec, x) -> bool:
         f"lag bound {fcfg.max_version_lag}, "
         f"affinities {list(fcfg.resolved_affinities())})"
     )
+    for rep in fleet.replicas:
+        devs = sorted(
+            {str(d) for leaf in jax.tree_util.tree_leaves(rep.engine.store.current.state)
+             if isinstance(leaf, jax.Array) for d in leaf.devices()}
+        )
+        print(f"  replica {rep.i}: state on {', '.join(devs)}")
 
     upd_futs = []
     sess = fleet.session()
@@ -527,6 +534,7 @@ def _run_fleet(args, spec, x) -> bool:
         settled = fleet.wait_settled(timeout=300)
         wall = time.perf_counter() - t0
         st = fleet.stats()
+        rep_stats = [rep.server.stats() for rep in fleet.replicas]
 
     # Per-version host oracles, exactly as _run_async: the fleet assigns vids
     # in submission order, so the replay below matches every replica.
@@ -569,7 +577,7 @@ def _run_fleet(args, spec, x) -> bool:
     ok = mismatches == 0 and served > 0 and settled
     if args.mutate:
         ok = ok and len(upd_futs) > 0
-    return ok
+    return ok, rep_stats
 
 
 def _span_attrs(engine: str, plan) -> dict:
@@ -638,11 +646,15 @@ def _export_trace(path: str, tracer, *, expect_requests: bool) -> bool:
     return ok
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> list:
+    """Run the CLI; returns the ``ServeStats`` of every server it ran (one
+    per replica in a fleet, none in oneshot and chaos modes). Exits 1 when
+    any check fails."""
     ap = _parser()
     args = ap.parse_args(argv)
     spec = registry.get(args.engine)
     _validate(ap, args, spec)
+    enable_compile_cache()
 
     tracer = None
     if args.trace is not None:
@@ -651,7 +663,7 @@ def main(argv=None) -> None:
         tracer = Tracer(enabled=True, capacity=1 << 17)
         set_tracer(tracer)
     try:
-        ok = _run_modes(ap, args, spec)
+        ok, stats = _run_modes(ap, args, spec)
     finally:
         if tracer is not None:
             set_tracer(None)
@@ -660,19 +672,19 @@ def main(argv=None) -> None:
         ok = _export_trace(args.trace, tracer, expect_requests=served_requests) and ok
     if not ok:
         raise SystemExit(1)
+    return stats
 
 
-def _run_modes(ap, args, spec) -> bool:
+def _run_modes(ap, args, spec):
+    """Build and serve per ``args``; returns ``(ok, [ServeStats, ...])``."""
     rng = np.random.default_rng(0)
     x = rng.random(args.n, dtype=np.float32)
 
     mesh, axes = _serve_mesh(args, spec)
     if args.chaos is not None:
         # Outside the mesh context on purpose: run_soak hands the mesh to the
-        # engines explicitly (like `python -m repro.fault.chaos`). Activating
-        # it globally switches jax 0.4.x sharded launches onto per-device
-        # rendezvous collectives, and two pool workers launching concurrently
-        # deadlock each other's rendezvous on the CPU backend.
+        # engines explicitly (like `python -m repro.fault.chaos`), so its
+        # pool workers' concurrent sharded launches see no ambient mesh.
         from repro.fault import chaos as chaos_mod
 
         report = chaos_mod.run_soak(
@@ -686,7 +698,7 @@ def _run_modes(ap, args, spec) -> bool:
             log=print,
         )
         print(report.summary())
-        return bool(report.ok)
+        return bool(report.ok), []
     if args.replicas > 1:
         # Outside any global mesh context: the fleet carves its own disjoint
         # per-replica device groups (serve.fleet.RMQFleet.build).
@@ -761,10 +773,8 @@ def _run_modes(ap, args, spec) -> bool:
         )
 
         if args.mode == "oneshot":
-            ok = _run_oneshot(args, spec, state, x, rng)
-        else:
-            ok = _run_async(args, spec, state, x, plan)
-    return bool(ok)
+            return _run_oneshot(args, spec, state, x, rng), []
+        return _run_async(args, spec, state, x, plan)
 
 
 if __name__ == "__main__":

@@ -64,6 +64,7 @@ from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core import build as build_mod
@@ -230,6 +231,7 @@ class _Replica:
         "root",
         "mesh",
         "axis_names",
+        "device",
         "server_cfg",
         "warmup_bounds",
         "lock",
@@ -243,7 +245,10 @@ class _Replica:
         "thread",
     )
 
-    def __init__(self, i, engine, server, affinity, *, root, mesh, axis_names, server_cfg, warmup_bounds):
+    def __init__(
+        self, i, engine, server, affinity, *, root, mesh, axis_names, server_cfg,
+        warmup_bounds, device=None,
+    ):
         self.i = i
         self.engine = engine
         self.server = server
@@ -251,6 +256,7 @@ class _Replica:
         self.root = root
         self.mesh = mesh
         self.axis_names = axis_names
+        self.device = device  # single-device engines: where it builds and serves
         self.server_cfg = server_cfg
         self.warmup_bounds = warmup_bounds
         self.lock = threading.Lock()  # guards active/gen/crash bookkeeping
@@ -360,7 +366,10 @@ class RMQFleet:
         """Build ``config.replicas`` serving stacks over ``x``.
 
         Mesh engines carve ``jax.devices()`` into disjoint equal groups, one
-        per replica (requires at least one device per replica). With
+        per replica (requires at least one device per replica). A
+        single-device engine's replica ``i`` builds and serves on
+        ``jax.devices()[i]`` (round robin past the device count): its array
+        is committed there, so every launch and publish follows it. With
         ``durable_root`` each replica journals under ``<root>/replica<i>``
         and crashed replicas can restore + rejoin; without it the fleet is
         in-memory and a crashed replica stays dead.
@@ -371,10 +380,8 @@ class RMQFleet:
             raise ValueError(f"fleet needs an updatable engine; {engine!r} is not")
         groups: List[Optional[list]] = [None] * cfg.replicas
         axis_names = None
+        devs = jax.devices()
         if spec.needs_mesh:
-            import jax
-
-            devs = jax.devices()
             if len(devs) < cfg.replicas:
                 raise ValueError(
                     f"{cfg.replicas} replicas need >= {cfg.replicas} devices, have {len(devs)}"
@@ -385,16 +392,20 @@ class RMQFleet:
         affs = cfg.resolved_affinities()
         reps: List[_Replica] = []
         for i in range(cfg.replicas):
-            mesh = make_group_mesh(groups[i]) if spec.needs_mesh else None
+            if spec.needs_mesh:
+                mesh, dev, xi = make_group_mesh(groups[i]), None, x
+            else:
+                mesh, dev = None, devs[i % len(devs)]
+                xi = jax.device_put(x, dev)
             if durable_root is not None:
                 root = os.path.join(durable_root, f"replica{i}")
                 eng = DurableEngine.create(
-                    engine, x, root, mesh=mesh, axis_names=axis_names,
+                    engine, xi, root, mesh=mesh, axis_names=axis_names,
                     fault=fault_plan, **build_kw,
                 )
             else:
                 root = None
-                eng = OnlineEngine(engine, x, mesh=mesh, axis_names=axis_names, **build_kw)
+                eng = OnlineEngine(engine, xi, mesh=mesh, axis_names=axis_names, **build_kw)
             scfg = dataclasses.replace(cfg.server, regime_affinity=affs[i])
             wb = build_mod.warmup_bounds(eng.plan)
             srv = RMQServer(
@@ -404,7 +415,7 @@ class RMQFleet:
                 _Replica(
                     i, eng, srv, affs[i],
                     root=root, mesh=mesh, axis_names=axis_names,
-                    server_cfg=scfg, warmup_bounds=wb,
+                    server_cfg=scfg, warmup_bounds=wb, device=dev,
                 )
             )
         return cls(reps, cfg, engine=engine, fault_plan=fault_plan, durable=durable_root is not None)
@@ -729,7 +740,8 @@ class RMQFleet:
                 if rep.active:
                     return
             eng = DurableEngine.restore(
-                rep.root, mesh=rep.mesh, axis_names=rep.axis_names, fault=self._fault_plan
+                rep.root, mesh=rep.mesh, axis_names=rep.axis_names,
+                device=rep.device, fault=self._fault_plan,
             )
             srv = RMQServer(
                 online=eng,

@@ -20,6 +20,7 @@ from typing import Callable, List, Optional, Tuple
 import numpy as np
 
 __all__ = [
+    "DISTS",
     "INT32_MAX",
     "make_queries",
     "poisson_interarrivals",
@@ -28,15 +29,26 @@ __all__ = [
 
 INT32_MAX = np.iinfo(np.int32).max
 
+# The three §6.4 regimes, and "mixed": each query drawn from one of them.
+DISTS = ("large", "medium", "small", "mixed")
+
 
 def make_queries(rng, n: int, batch: int, dist: str):
     """Paper §6.4 range distributions (large / medium / small) -> int32 (l, r).
 
     Large: uniform range length in [1, n]; Medium: LogNormal(log n^0.6, .3);
-    Small: LogNormal(log n^0.3, .3).
+    Small: LogNormal(log n^0.3, .3). Mixed: each query from one of the three,
+    chosen uniformly — the traffic a regime-routing front door splits.
     """
     if not 1 <= n <= INT32_MAX:
         raise ValueError(f"n={n} outside the engines' int32 index range")
+    if dist == "mixed":
+        pick = rng.integers(0, 3, batch)
+        parts = [make_queries(rng, n, batch, d) for d in DISTS[:3]]
+        return (
+            np.choose(pick, [p[0] for p in parts]),
+            np.choose(pick, [p[1] for p in parts]),
+        )
     if dist == "large":
         length = rng.integers(1, n + 1, batch)
     else:

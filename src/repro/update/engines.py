@@ -47,7 +47,7 @@ from repro.core import block_rmq, distributed, packing, registry, sparse_table
 from repro.core import build as build_mod
 from repro.obs import trace as obs_trace
 from repro.core.block_rmq import BlockRMQ
-from repro.core.hybrid import HybridRMQ
+from repro.core.hybrid import HybridRMQ, block_query, long_query
 from repro.core.sparse_table import SparseTable
 
 from .deltas import DeltaBatch, DeltaLog, shard_batches
@@ -103,19 +103,6 @@ class UpdateResult(NamedTuple):
     publish_bytes: int = 0
 
 
-# Module-level jitted query closures for published hybrid versions: binding a
-# new same-shape structure is a jit-cache hit, so publishing never retraces.
-_block_query_jit = jax.jit(block_rmq.query)
-
-
-def _st_long(table: SparseTable, x, l, r):
-    idx = sparse_table.query(table, l, r)
-    return idx, x[idx]
-
-
-_st_long_jit = jax.jit(_st_long)
-
-
 def _block_state(m: BlockMirror) -> BlockRMQ:
     bmin = jnp.asarray(m.bmin_val)
     return BlockRMQ(
@@ -139,6 +126,14 @@ def _block_state(m: BlockMirror) -> BlockRMQ:
 # boundary. Window lengths are padded to powers of two (the padding uploads
 # unchanged-but-correct mirror content) so the jit cache stays bounded at
 # ~log2(n) shapes per leaf.
+
+
+def _put(host, like):
+    """Upload ``host`` beside ``like``: onto its device when ``like`` is
+    committed to one (a fleet replica's array), else the default device."""
+    if isinstance(like, jax.Array) and like.committed:
+        return jax.device_put(host, like.sharding)
+    return jnp.asarray(host)
 
 
 def _cow_splice(dev, wins, starts):
@@ -176,7 +171,7 @@ class _CowLeaf:
         self._counter = counter
 
     def full(self, host):
-        self.dev = jnp.asarray(host)
+        self.dev = _put(host, self.dev)
         self._counter["bytes"] += int(self.dev.nbytes)
         return self.dev
 
@@ -218,14 +213,14 @@ class _CowLeaf:
 class _BlockLeaves:
     """The four device leaves of a ``BlockRMQ``, published copy-on-write."""
 
-    def __init__(self, m: BlockMirror, counter, state: Optional[BlockRMQ] = None):
+    def __init__(self, m: BlockMirror, counter, state: Optional[BlockRMQ] = None, like=None):
         if state is None:  # restore: seed from the mirror (no argmin rebuild)
-            bv = jnp.asarray(m.bmin_val)
+            bv = _put(m.bmin_val, like)
             state = BlockRMQ(
-                x_blocks=jnp.asarray(m.x_blocks),
+                x_blocks=_put(m.x_blocks, like),
                 bmin_val=bv,
-                bmin_gidx=jnp.asarray(m.bmin_gidx),
-                st=SparseTable(idx=jnp.asarray(m.st_idx), x=bv),
+                bmin_gidx=_put(m.bmin_gidx, like),
+                st=SparseTable(idx=_put(m.st_idx, like), x=bv),
             )
         self.xb = _CowLeaf(state.x_blocks, counter)
         self.bv = _CowLeaf(state.bmin_val, counter)
@@ -295,8 +290,8 @@ def _sparse_table_impl(x, mesh, axis_names, kw, snap=None) -> _Impl:
         x_leaf = _CowLeaf(state0[1], pub)
     else:
         mirror = STMirror(snap["st_idx"], snap["x"])
-        idx_leaf = _CowLeaf(jnp.asarray(mirror.idx), pub)
-        x_leaf = _CowLeaf(jnp.asarray(mirror.x), pub)
+        idx_leaf = _CowLeaf(_put(mirror.idx, x), pub)
+        x_leaf = _CowLeaf(_put(mirror.x, x), pub)
         state0 = (SparseTable(idx=idx_leaf.dev, x=x_leaf.dev), x_leaf.dev)
 
     def patch(batch: DeltaBatch, prev):
@@ -339,7 +334,7 @@ def _block_impl(block_size: int):
                 snap["st_idx"],
                 snap["x"].shape[0],
             )
-            leaves = _BlockLeaves(mirror, pub)
+            leaves = _BlockLeaves(mirror, pub, like=x)
             state0 = leaves.state()
 
         def patch(batch: DeltaBatch, prev):
@@ -388,8 +383,8 @@ def _hybrid_impl(x, mesh, axis_names, kw, snap=None) -> _Impl:
             x=xj,
             threshold=threshold,
             use_kernels=False,
-            short_fn=functools.partial(_block_query_jit, blocked),
-            long_fn=functools.partial(_st_long_jit, table, xj),
+            short_fn=functools.partial(block_query, blocked),
+            long_fn=functools.partial(long_query, table, xj),
         )
 
     if snap is None:
@@ -408,9 +403,9 @@ def _hybrid_impl(x, mesh, axis_names, kw, snap=None) -> _Impl:
             snap["x"].shape[0],
         )
         st_m = STMirror(snap["st_idx"], snap["x"])
-        leaves = _BlockLeaves(blocked_m, pub)
-        ti_leaf = _CowLeaf(jnp.asarray(st_m.idx), pub)
-        x_leaf = _CowLeaf(jnp.asarray(st_m.x), pub)
+        leaves = _BlockLeaves(blocked_m, pub, like=x)
+        ti_leaf = _CowLeaf(_put(st_m.idx, x), pub)
+        x_leaf = _CowLeaf(_put(st_m.x, x), pub)
         # The snapshot was taken under the plan's resolved threshold (the
         # restore kwargs pin it), so routing is identical to the live engine.
         state0 = _assemble(
@@ -536,10 +531,10 @@ def _packed_hybrid_impl(x, mesh, axis_names, kw, snap=None) -> _Impl:
         )
         st_m = PackedSTMirror(snap["st_words"], snap["x"], spec)
         leaves = {
-            "blocks": _CowLeaf(jnp.asarray(snap["b_blocks"]), pub),
-            "stw": _CowLeaf(jnp.asarray(snap["b_stw"]), pub),
-            "words": _CowLeaf(jnp.asarray(snap["st_words"]), pub),
-            "x": _CowLeaf(jnp.asarray(snap["x"]), pub),
+            "blocks": _CowLeaf(_put(snap["b_blocks"], x), pub),
+            "stw": _CowLeaf(_put(snap["b_stw"], x), pub),
+            "words": _CowLeaf(_put(snap["st_words"], x), pub),
+            "x": _CowLeaf(_put(snap["x"], x), pub),
         }
         state0 = _assemble(
             block_rmq.PackedBlockRMQ(
@@ -999,14 +994,16 @@ class OnlineEngine:
             return arrays, meta
 
     @classmethod
-    def from_snapshot(cls, arrays, meta, *, mesh=None, axis_names=None):
+    def from_snapshot(cls, arrays, meta, *, mesh=None, axis_names=None, device=None):
         """Reconstruct an engine from ``snapshot()`` output.
 
         Version ids continue from the snapshot's vid (the restored initial
         publish IS that version). Meshes are not serializable — the caller
-        supplies the current process's mesh for mesh engines.
+        supplies the current process's mesh for mesh engines, or the
+        ``device`` a single-device engine is restored onto.
         """
-        x = jnp.asarray(np.ascontiguousarray(arrays["x"]))
+        x = np.ascontiguousarray(arrays["x"])
+        x = jax.device_put(x, device) if device is not None else jnp.asarray(x)
         return cls(
             meta["engine"],
             x,

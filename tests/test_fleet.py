@@ -357,3 +357,46 @@ def test_sharded_fleet_on_8_device_mesh():
     8-fake-device mesh: full soak with crash + restore, oracle-verified."""
     out = _run_child(_CHILD_FLEET8)
     assert "FLEET8_OK" in out.stdout, out.stderr[-3000:]
+
+
+_CHILD_PLACEMENT = textwrap.dedent(
+    """
+    import tempfile
+    import jax, numpy as np
+    from repro.serve import ServeConfig
+    from repro.serve.fleet import FleetConfig, RMQFleet
+    from repro.update import DeltaLog
+
+    def devices(rep):
+        leaves = jax.tree_util.tree_leaves(rep.engine.store.current.state)
+        return {d.id for a in leaves if isinstance(a, jax.Array) for d in a.devices()}
+
+    x = np.random.default_rng(0).standard_normal(2048).astype(np.float32)
+    cfg = FleetConfig(replicas=3, max_version_lag=2, server=ServeConfig(workers=1, max_retries=8))
+    fleet = RMQFleet.build("hybrid", x, config=cfg, durable_root=tempfile.mkdtemp())
+    try:
+        assert [devices(r) for r in fleet.replicas] == [{0}, {1}, {2}]
+        log = DeltaLog().point(5, -9.0)
+        log.append(np.ones(200, np.float32))  # growth: leaves re-uploaded whole
+        fleet.submit_update(log).result(timeout=120)
+        assert fleet.wait_settled(timeout=120)
+        fleet.crash_replica(2)
+        fleet.restore_replica(2)
+        assert [devices(r) for r in fleet.replicas] == [{0}, {1}, {2}]
+        for rep in fleet.replicas:
+            res = rep.server.submit(
+                np.array([0], np.int32), np.array([2247], np.int32), min_version=1
+            ).result(timeout=60)
+            assert res.idx[0] == 5, res.idx
+        print("PLACEMENT_OK")
+    finally:
+        fleet.close()
+    """
+)
+
+
+def test_single_device_replicas_build_and_serve_on_their_own_device():
+    """Replica i of a single-device engine lives on device i — after build,
+    after a publish that re-uploads its leaves, and after crash + restore."""
+    out = _run_child(_CHILD_PLACEMENT)
+    assert "PLACEMENT_OK" in out.stdout, out.stderr[-3000:]

@@ -86,3 +86,26 @@ def test_kernel_vs_pure_jnp_engine(rng):
     i2, v2 = block_rmq.query(s2, jnp.asarray(l), jnp.asarray(r))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
+
+
+# --- interpret vs compiled ----------------------------------------------------
+
+
+def test_interpret_follows_the_backend(monkeypatch):
+    from repro.kernels import tiling
+
+    assert tiling.resolve_interpret(None) is True  # the CPU backend interprets
+    assert tiling.resolve_interpret(True, tile=3, row=256) is True
+    monkeypatch.setattr(tiling.jax, "default_backend", lambda: "tpu")
+    assert tiling.resolve_interpret(None, tile=8, row=128) is False
+    monkeypatch.setattr(tiling.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        tiling.resolve_interpret(None)  # never interpret quietly on a device
+
+
+@pytest.mark.parametrize("tile,row", [(4, 128), (8, 256)])
+def test_compiled_launch_rejects_shapes_the_tpu_refuses(tile, row):
+    from repro.kernels import tiling
+
+    with pytest.raises(ValueError):
+        tiling.resolve_interpret(False, tile=tile, row=row)

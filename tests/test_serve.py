@@ -418,6 +418,17 @@ def test_make_queries_int32_boundary():
         make_queries(rng, 2**31 + 5, 4, "small")
 
 
+def test_make_queries_mixed_draws_every_regime():
+    n = 1 << 20
+    l, r = make_queries(np.random.default_rng(3), n, 600, "mixed")
+    assert l.dtype == np.int32 and r.dtype == np.int32
+    assert (l >= 0).all() and (l <= r).all() and (r < n).all()
+    length = r.astype(np.int64) - l + 1
+    assert (length < 4 * n**0.3).sum() > 100  # small
+    assert ((length > n**0.6 / 4) & (length < 4 * n**0.6)).sum() > 100  # medium
+    assert (length > 8 * n**0.6).sum() > 100  # large
+
+
 # --- registry capability metadata -------------------------------------------
 
 

@@ -133,7 +133,7 @@ def test_sweep_times_every_candidate_through_the_seam(monkeypatch):
 
 def test_tuned_policy_sweeps_once_then_hits(tmp_path, monkeypatch):
     p = tmp_path / "cal.json"
-    want = tuning.KernelConfig(4, "resident", 128)
+    want = tuning.KernelConfig(16, "resident", 128)
     monkeypatch.setattr(hybrid, "_measure", _fake_measure_preferring(want))
     kw = dict(block_size=128, backend="cpu", n_devices=1, path=p)
     cfg = tuning.get_config(1 << 12, 64, policy="tuned", interpret=True, **kw)
