@@ -24,23 +24,23 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src"))
 
 # One chip: the largest power of two whose hybrid build fits. At 2^26 the
-# doubling table's 27 rows pad to 32 (8 GiB) and the build peaks at 9.0 GiB
-# of the 16 GiB HBM (v5e compile, memory_analysis); 2^27 would need 16 GiB
-# for the table alone. nb = 2^19 blocks, so the short path uses the dma fetch.
+# doubling table's 27 rows pad to 32 (8 GiB) and the build holds 9.4 GiB of
+# the 16 GiB HBM (tests/test_chip_compile.py); 2^27 would need 16 GiB for
+# the table alone. nb = 2^19 blocks, so the short path uses the dma fetch.
 N_HYBRID = 1 << 26
 N_HYBRID_WHY = (
     "n = 2^26: the doubling table pads 27 -> 32 rows (8 GiB); the build "
-    "peaks at 9.0 GiB of 16 GiB HBM (v5e compile, memory_analysis)"
+    "holds 9.4 GiB of 16 GiB HBM (v5e compile, memory_analysis)"
 )
 N_RESIDENT = 1 << 20  # nb = 2^13 blocks: the largest resident-fetch size
 N_UPDATE = 1 << 24
-# Four chips: the sharded doubling table keeps indices and values, 16 GiB at
-# 2^26 (4 GiB per chip): more than one chip holds. At 2^27 its halo build
-# needs 15.0 GiB per chip (v5e compile, memory_analysis).
-N_SHARDED = 1 << 26
+# Four chips: the sharded doubling table keeps indices and values, 32 GiB at
+# 2^27 (8 GiB per chip): more than one chip holds. Its halo build and the
+# served structures hold 9.3 GiB per chip (tests/test_chip_compile.py).
+N_SHARDED = 1 << 27
 N_SHARDED_WHY = (
-    "n = 2^26: its (idx, val) doubling table is 16 GiB, 4 GiB per chip; at "
-    "2^27 the halo build needs 15.0 GiB per chip (v5e compile, memory_analysis)"
+    "n = 2^27: its (idx, val) doubling table is 32 GiB, 8 GiB per chip; the "
+    "halo build holds 9.3 GiB per chip (v5e compile, memory_analysis)"
 )
 N_FLEET = 1 << 24
 
@@ -82,7 +82,7 @@ def memory_report(jax) -> None:
         m = d.memory_stats() or {}
         say(
             f"{d}: bytes_in_use={m.get('bytes_in_use')} "
-            f"peak_bytes_in_use={m.get('peak_bytes_in_use')}"
+            f"peak_bytes_in_use={m.get('peak_bytes_in_use')} bytes_limit={m.get('bytes_limit')}"
         )
 
 

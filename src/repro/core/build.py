@@ -189,6 +189,15 @@ def _resolve_kernel_config(kernel_config, n: int, block_size: int | None = None)
     return tuning.KernelConfig(*kernel_config)
 
 
+def _check_kernel_block_size(block_size: int) -> None:
+    """Refuse, when the plan is made, a block size the kernels cannot launch
+    with on this backend (compiled launches DMA 128-lane rows), rather than
+    after the whole build, at the first warm-up launch."""
+    from repro.kernels import tiling  # deferred: keep core importable alone
+
+    tiling.resolve_interpret(None, row=block_size)
+
+
 def _norm_packed(packed) -> Optional[str]:
     """Normalise the ``packed=`` build kwarg to a layout request or ``None``.
 
@@ -436,6 +445,7 @@ def _plan_fused(
     # along the same way: the config's own layout field wins unless the
     # caller pins one via ``packed=``.
     bs = block_size if block_size is not None else cfg.block_size
+    _check_kernel_block_size(bs)
     if layout is None and cfg.layout != "unpacked":
         layout = cfg.layout
     if layout == "packed64":
@@ -483,6 +493,8 @@ def _plan_hybrid(
     pack_layout = _norm_packed(packed)
     if use_kernels is None:
         use_kernels = jax.default_backend() == "tpu"
+    if use_kernels:
+        _check_kernel_block_size(block_size)
     thr = _resolve_threshold(
         threshold,
         n,

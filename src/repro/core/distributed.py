@@ -367,14 +367,17 @@ def _st_halo_fn(mesh: Mesh, axis_names: Tuple[str, ...], n_pad: int, num: int):
         flat = _flat_axis_index(axis_names)
         cols = jnp.arange(shard_len, dtype=jnp.int32)
         is_last = flat == num - 1
-        idx_rows, val_rows = [idx], [val]
+        # Rows are written into the preallocated tables in place, so only
+        # the current level stays live beside them (not every level's row).
+        idx_tab = jnp.zeros((k_levels, shard_len), idx.dtype).at[0].set(idx)
+        val_tab = jnp.zeros((k_levels, shard_len), val.dtype).at[0].set(val)
         for k in range(1, k_levels):
             h = 1 << (k - 1)
             if h >= n_pad:
                 # Window spans the whole array: rows repeat from here on
                 # (sparse_table.build appends cur unchanged).
-                idx_rows.append(idx)
-                val_rows.append(val)
+                idx_tab = idx_tab.at[k].set(idx)
+                val_tab = val_tab.at[k].set(val)
                 continue
             d, r = divmod(h, shard_len)
             wi = _flat_shift(idx, mesh, axis_names, d)
@@ -398,9 +401,9 @@ def _st_halo_fn(mesh: Mesh, axis_names: Tuple[str, ...], n_pad: int, num: int):
             take = val <= wv  # leftmost-tie: prefer the unshifted (left) row
             idx = jnp.where(take, idx, wi)
             val = jnp.where(take, val, wv)
-            idx_rows.append(idx)
-            val_rows.append(val)
-        return jnp.stack(idx_rows), jnp.stack(val_rows)
+            idx_tab = idx_tab.at[k].set(idx)
+            val_tab = val_tab.at[k].set(val)
+        return idx_tab, val_tab
 
     return jax.jit(
         shard_map(

@@ -62,8 +62,10 @@ from repro.core.sparse_table import exact_log2
 
 from .tiling import (
     LANES,
+    block_rows,
     lane_pick,
     pad_to_tiles,
+    partial_candidates,
     resolve_interpret,
     row_copies,
     run_copies,
@@ -109,15 +111,6 @@ def _decompose(l, r, bs: int, nb: int):
     return [bl, br, ls, le, re, k, ilo, bpos, hasint, wlo, whi]
 
 
-def block_rows(x_hbm, bl_ref, br_ref, q0, tile, xl_buf, xr_buf, sems):
-    """The DMAs staging each query's left and right partial-block rows."""
-    return row_copies(
-        [x_hbm.at[pl.ds(bl_ref[q0 + t], 1)] for t in range(tile)], xl_buf, sems.at[0]
-    ) + row_copies(
-        [x_hbm.at[pl.ds(br_ref[q0 + t], 1)] for t in range(tile)], xr_buf, sems.at[1]
-    )
-
-
 def _windows(t_hbm, k_ref, w_ref, q0, tile, buf, sem):
     """The DMAs of each query's window ``t_hbm[k[q], w[q]]`` of a
     ``(K, nbp // 128, 128)`` windowed table (:func:`as_windows`)."""
@@ -134,32 +127,6 @@ def as_windows(table: jax.Array, fill) -> jax.Array:
     return jnp.pad(table, ((0, 0), (0, nbp - nb)), constant_values=fill).reshape(
         k, nbp // _W, _W
     )
-
-
-def partial_candidates(xl, xr, bl, br, ls, le, re, big):
-    """Left/right partial-block candidates of a tile, leftmost ties.
-
-    ``xl``/``xr`` are the ``(tile, bs)`` staged rows; the scalars are
-    ``(tile, 1)`` columns. Returns ``(pv, pi)`` columns.
-    """
-    bs = xl.shape[1]
-    big_i = jnp.int32(bs)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, xl.shape, 1)
-
-    ml = jnp.where((lanes >= ls) & (lanes <= le), xl, big)
-    lv = jnp.min(ml, axis=1, keepdims=True)
-    li = jnp.min(jnp.where(ml == lv, lanes, big_i), axis=1, keepdims=True)
-    lg = bl * bs + li
-
-    # Right partials (masked off for single-block queries).
-    mr = jnp.where(lanes <= re, xr, big)
-    rv = jnp.min(mr, axis=1, keepdims=True)
-    rv = jnp.where(br > bl, rv, big)
-    ri = jnp.min(jnp.where(mr == rv, lanes, big_i), axis=1, keepdims=True)
-    rg = br * bs + ri
-
-    take_l = lv <= rv  # left candidate has smaller indices: leftmost ties
-    return jnp.where(take_l, lv, rv), jnp.where(take_l, lg, rg)
 
 
 def _merge(pv, pi, iv, ii, bl, bs, val_ref, idx_ref):
@@ -253,8 +220,8 @@ def fused_query(
     l: jax.Array,  # (B,)
     r: jax.Array,  # (B,)
     *,
-    st_val: jax.Array | None = None,  # value-augmented table (dma), windowed
-    st_gidx: jax.Array | None = None,  # gidx-augmented table (dma), windowed
+    st_val: jax.Array | None = None,  # bmin_val[st_idx], windowed (K, nbp // 128, 128)
+    st_gidx: jax.Array | None = None,  # bmin_gidx[st_idx] int32, windowed likewise
     tile: int = DEFAULT_TILE,
     fetch: str = "auto",
     interpret: bool | None = None,
@@ -319,8 +286,6 @@ def fused_query(
                         "materialize_interior=True to opt in silently."
                     )
             st_val, st_gidx = interior_tables(bmin_val, bmin_gidx, st_idx)
-        elif st_val.ndim == 2:  # (K, nb) tables: relaid out to windows per call
-            st_val, st_gidx = as_windows(st_val, big), as_windows(st_gidx, 0)
         in_specs = [_ANY, _ANY, _ANY]  # x_blocks rows; st_val, st_gidx windows
         operands = (x_blocks, st_val, st_gidx)
         vwin = pltpu.VMEM((tile, _W), x_blocks.dtype)
@@ -356,7 +321,7 @@ def fused_query(
 #   * the interior candidate is two cells of the packed doubling table
 #     ``stw`` — TWO (1, 128) windows per query where the unpacked dma
 #     kernel fetches FOUR (value + gidx at each position), and NO resident
-#     planes at all, so packed32 has one kernel for either ``fetch``;
+#     planes at all, so packed32 has one kernel and no ``fetch`` knob;
 #   * the final merge is ``min`` of three words — the leftmost-tie
 #     select chain is subsumed by word order, and the kernel emits one
 #     packed word per query that the host unpacks.
@@ -448,9 +413,7 @@ def _kernel_quantized(tile, idx_bits, *refs):
     _merge(pv, pi, iv, ii, bl, bs, val_ref, idx_ref)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("spec", "tile", "fetch", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("spec", "tile", "interpret"))
 def fused_query_packed(
     blocks: jax.Array,  # (nb, bs): packed words (packed32) | raw values (quantized)
     stw: jax.Array,  # (K, nb) int32 packed doubling table over block minima
@@ -460,16 +423,15 @@ def fused_query_packed(
     spec,  # packing.PackSpec (static: hashable NamedTuple of primitives)
     bmin_val: jax.Array | None = None,  # (nb,) exact minima (quantized only)
     tile: int = DEFAULT_TILE,
-    fetch: str = "auto",
     interpret: bool | None = None,
 ):
     """Packed fused blocked RMQ. Returns (idx (B,) int32, value (B,)).
 
     One kernel dispatch per batch over single-plane packed structures (see
-    the section comment above for the per-layout fetch volumes). Layouts:
-    packed32 (exact; one kernel for either ``fetch``, which is validated)
-    and quantized (resident only). packed64 raises — int64 words have no
-    TPU kernel path.
+    the section comment above for the per-layout fetch volumes). Each layout
+    has one kernel, so there is no ``fetch`` knob: packed32 DMAs ``stw``
+    windows only, quantized adds its resident ``bmin_val`` plane. packed64
+    raises — int64 words have no TPU kernel path.
     """
     nb, bs = blocks.shape
     interpret = resolve_interpret(interpret, tile, bs)
@@ -481,7 +443,6 @@ def fused_query_packed(
     if spec.layout not in ("packed32", "quantized"):
         raise ValueError(f"fused_query_packed wants packed32|quantized, got {spec.layout!r}")
     b = l.shape[0]
-    resolve_fetch(fetch, nb)
     pad = packing.pad_word(spec)
 
     scalars, bp = pad_to_tiles(_decompose(l, r, bs, nb), b, tile)

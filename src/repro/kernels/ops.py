@@ -135,21 +135,19 @@ def query_packed(
     *,
     config: KernelConfig | None = None,
     tile: int | None = None,
-    fetch: str | None = None,
     interpret: bool | None = None,
 ):
     """Packed megakernel batched query -> (leftmost argmin idx int32, value).
 
     Mirrors :func:`query` over ``PackedFusedRMQ`` state; the launch
-    geometry comes from ``config`` (its ``layout`` field is the tuner's
-    bookkeeping — the structure's ``spec`` is authoritative here).
+    geometry comes from ``config`` (its ``layout`` and ``fetch`` fields are
+    the tuner's bookkeeping — the structure's ``spec`` is authoritative
+    here, and each packed layout has one kernel).
     """
     if config is None:
         config = KernelConfig()
     if tile is None:
         tile = config.tile
-    if fetch is None:
-        fetch = config.fetch
     return fused_query_packed(
         s.blocks,
         s.stw,
@@ -158,7 +156,6 @@ def query_packed(
         spec=spec,
         bmin_val=s.bmin_val,
         tile=tile,
-        fetch=fetch,
         interpret=interpret,
     )
 

@@ -28,8 +28,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.block_rmq import maxval
 
-from .fused_query import block_rows, partial_candidates
-from .tiling import pad_to_tiles, resolve_interpret, run_copies, scalar_col, tile_out_specs
+from .tiling import (
+    block_rows,
+    pad_to_tiles,
+    partial_candidates,
+    resolve_interpret,
+    run_copies,
+    scalar_col,
+    tile_out_specs,
+)
 from .tuning import DEFAULT_TILE
 
 __all__ = ["rmq_partials", "DEFAULT_TILE"]

@@ -10,8 +10,8 @@ block breaks the (8, 128) tiling rule. Per-query scalars and single-cell
 picks are then vectorised over the tile as ``(tile, 1)`` columns, because
 Mosaic cannot read one lane at a dynamic offset.
 
-The batch padding, SMEM scalar columns and lane picks are shared by every
-query kernel.
+The batch padding, SMEM scalar columns, lane picks, block-row DMAs and
+partial-block scans are shared by every query kernel.
 """
 
 from __future__ import annotations
@@ -24,8 +24,10 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = [
     "LANES",
     "SUBLANES",
+    "block_rows",
     "lane_pick",
     "pad_to_tiles",
+    "partial_candidates",
     "resolve_interpret",
     "row_copies",
     "run_copies",
@@ -118,6 +120,41 @@ def run_copies(copies) -> None:
         c.start()
     for c in copies:
         c.wait()
+
+
+def block_rows(x_hbm, bl_ref, br_ref, q0, tile, xl_buf, xr_buf, sems):
+    """The DMAs staging each query's left and right partial-block rows."""
+    return row_copies(
+        [x_hbm.at[pl.ds(bl_ref[q0 + t], 1)] for t in range(tile)], xl_buf, sems.at[0]
+    ) + row_copies(
+        [x_hbm.at[pl.ds(br_ref[q0 + t], 1)] for t in range(tile)], xr_buf, sems.at[1]
+    )
+
+
+def partial_candidates(xl, xr, bl, br, ls, le, re, big):
+    """Left/right partial-block candidates of a tile, leftmost ties.
+
+    ``xl``/``xr`` are the ``(tile, bs)`` staged rows; the scalars are
+    ``(tile, 1)`` columns. Returns ``(pv, pi)`` columns.
+    """
+    bs = xl.shape[1]
+    big_i = jnp.int32(bs)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, xl.shape, 1)
+
+    ml = jnp.where((lanes >= ls) & (lanes <= le), xl, big)
+    lv = jnp.min(ml, axis=1, keepdims=True)
+    li = jnp.min(jnp.where(ml == lv, lanes, big_i), axis=1, keepdims=True)
+    lg = bl * bs + li
+
+    # Right partials (masked off for single-block queries).
+    mr = jnp.where(lanes <= re, xr, big)
+    rv = jnp.min(mr, axis=1, keepdims=True)
+    rv = jnp.where(br > bl, rv, big)
+    ri = jnp.min(jnp.where(mr == rv, lanes, big_i), axis=1, keepdims=True)
+    rg = br * bs + ri
+
+    take_l = lv <= rv  # left candidate has smaller indices: leftmost ties
+    return jnp.where(take_l, lv, rv), jnp.where(take_l, lg, rg)
 
 
 def tile_out_specs(tile: int, n: int = 2):

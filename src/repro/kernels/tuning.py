@@ -119,9 +119,11 @@ def candidate_configs(n: int, block_size: int | None = None, *, layouts=None):
     ``layouts`` opts the packed-structure axis in (e.g. ``TUNE_LAYOUTS``);
     the default sweeps only "unpacked". Statically-infeasible members are
     excluded here: packed64 words are int64 (outside the TPU kernel
-    vocabulary — packed64 serves through the XLA packed engines instead)
-    and the quantized fallback hop keeps a resident plane, so it has no
-    bounded-VMEM dma strategy. packed32's *data*-dependent feasibility
+    vocabulary — packed64 serves through the XLA packed engines instead),
+    the quantized fallback hop keeps a resident plane, so it has no
+    bounded-VMEM dma strategy, and packed32 has one kernel that fetches
+    only DMA windows, so it is swept once per tile, as "dma". packed32's
+    *data*-dependent feasibility
     (does the key range fit?) is settled by ``sweep`` per array.
     """
     sizes = (block_size,) if block_size is not None else TUNE_BLOCK_SIZES
@@ -137,6 +139,8 @@ def candidate_configs(n: int, block_size: int | None = None, *, layouts=None):
             continue  # int64 words: no kernel path
         if lay == "quantized" and fetch == "dma":
             continue  # fallback hop needs the resident exact-minima plane
+        if lay == "packed32" and fetch == "resident":
+            continue  # one kernel, DMA windows only: timed once, as "dma"
         out.append(KernelConfig(tile=tile, fetch=fetch, block_size=bs, layout=lay))
     for bs in sizes:  # the resolved default, if the product missed it
         d = KernelConfig(DEFAULT_TILE, resolve_fetch("auto", -(-n // bs)), bs)

@@ -109,3 +109,16 @@ def test_compiled_launch_rejects_shapes_the_tpu_refuses(tile, row):
 
     with pytest.raises(ValueError):
         tiling.resolve_interpret(False, tile=tile, row=row)
+
+
+@pytest.mark.parametrize("engine,kw", [("hybrid", {"threshold": 64}), ("fused", {})])
+def test_plan_rejects_a_block_size_the_chip_cannot_launch(monkeypatch, engine, kw):
+    """On a TPU a kernel engine with 256-wide blocks is refused when it is
+    planned, before the build, not at its first warm-up launch."""
+    from repro.core import build as build_mod
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="128-lane"):
+        build_mod.plan_for(engine, 1 << 12, block_size=256, **kw)
+    plan = build_mod.plan_for(engine, 1 << 12, block_size=128, **kw)
+    assert plan.meta["block_size"] == 128

@@ -345,9 +345,10 @@ def test_tuned_layout_winner_round_trips(tmp_path):
 def test_candidate_configs_layout_feasibility():
     """The swept layout axis excludes what can never run: packed64 has no
     kernel path (int64 words), quantized has no dma strategy (the exact
-    fallback needs its resident plane)."""
+    fallback needs its resident plane), and packed32's one kernel is timed
+    once per tile."""
     cands = tuning.candidate_configs(4096, 128, layouts=tuning.TUNE_LAYOUTS)
-    assert any(c.layout == "packed32" for c in cands)
+    assert {c.fetch for c in cands if c.layout == "packed32"} == {"dma"}
     assert any(c.layout == "quantized" and c.fetch == "resident" for c in cands)
     assert not any(c.layout == "packed64" for c in cands)
     assert not any(c.layout == "quantized" and c.fetch == "dma" for c in cands)
