@@ -131,6 +131,21 @@ def record_splits(cb):
         _split_sink.cb = prev
 
 
+def _pad_pow2(lm: np.ndarray, rm: np.ndarray):
+    """``(l, r, k)``: a sub-batch of ``k`` queries padded with (0, 0) to a
+    power of two, so the jit cache stays bounded (log2(B) shapes per path)
+    however batch sizes and splits vary."""
+    k = lm.size
+    kp = 1 << (k - 1).bit_length() if k > 1 else 1
+    if kp != k:
+        lp = np.zeros(kp, np.int64)
+        rp = np.zeros(kp, np.int64)
+        lp[:k] = lm
+        rp[:k] = rm
+        lm, rm = lp, rp
+    return lm, rm, k
+
+
 def dispatch_by_length(l, r, threshold: int, short_fn, long_fn, out_dtype):
     """Range-adaptive dispatch core, shared by ``hybrid`` and ``sharded_hybrid``.
 
@@ -143,61 +158,67 @@ def dispatch_by_length(l, r, threshold: int, short_fn, long_fn, out_dtype):
     constituent engine computes int32 indices, so an out-of-range bound
     would wrap silently instead of failing loudly — checked here, the one
     query path both hybrids share.
+
+    Each phase is a span under the caller's ambient one (the server's
+    ``launch``): ``prepare`` (checks, casts, partition, padding), then per
+    sub-batch ``h2d`` (its bounds to the device) and ``enqueue`` (its
+    asynchronous launch), and on a mixed batch per sub-batch ``wait`` (on
+    its launch) and ``merge`` (its answers to the host, scattered into
+    batch order), with a last ``merge`` (the whole answer back to the
+    device).
     """
-    l = np.asarray(l)
-    r = np.asarray(r)
-    if not (np.issubdtype(l.dtype, np.integer) and np.issubdtype(r.dtype, np.integer)):
-        raise TypeError(f"query bounds must be integer arrays, got {l.dtype} / {r.dtype}")
-    l = l.astype(np.int64)
-    r = r.astype(np.int64)
-    if l.size == 0:  # nothing to do: no phantom padded query, no launch
-        return jnp.zeros(0, jnp.int32), jnp.zeros(0, out_dtype)
-    if int(l.min()) < 0 or int(r.max()) > _INT32_MAX:
-        raise ValueError(
-            f"query bounds [{int(l.min())}, {int(r.max())}] outside the engines' "
-            "int32 index range"
-        )
-    short = (r - l + 1) <= threshold
-    cb = getattr(_split_sink, "cb", None)
-    if cb is not None:
-        cb(int(short.sum()), int(l.size - short.sum()))
-    # Regime split onto the ambient trace span (the server's launch span
-    # when tracing is on) — obs.set_attr is a no-op outside any span.
-    if obs_trace.get_tracer().enabled:
-        obs_trace.set_attr("split_short", int(short.sum()))
-        obs_trace.set_attr("split_long", int(l.size - short.sum()))
-
-    # Every launch pads its batch to a power of two so the jit cache stays
-    # bounded (log2(B) shapes per path) however batch sizes and splits vary.
-    def _launch(fn, lm, rm):
-        k = lm.size
-        kp = 1 << (k - 1).bit_length() if k > 1 else 1
-        if kp != k:
-            lp = np.zeros(kp, np.int64)
-            rp = np.zeros(kp, np.int64)
-            lp[:k] = lm
-            rp[:k] = rm
-            lm, rm = lp, rp
-        qi, qv = fn(jnp.asarray(lm), jnp.asarray(rm))
-        return qi, qv, k
-
-    # Uniform batches skip the partition/scatter round-trip entirely.
-    n_short = int(short.sum())
-    if n_short == short.size or n_short == 0:
-        qi, qv, k = _launch(short_fn if n_short else long_fn, l, r)
+    tr = obs_trace.get_tracer()
+    with tr.span("prepare"):
+        l = np.asarray(l)
+        r = np.asarray(r)
+        if not (np.issubdtype(l.dtype, np.integer) and np.issubdtype(r.dtype, np.integer)):
+            raise TypeError(f"query bounds must be integer arrays, got {l.dtype} / {r.dtype}")
+        l = l.astype(np.int64)
+        r = r.astype(np.int64)
+        if l.size == 0:  # nothing to do: no phantom padded query, no launch
+            return jnp.zeros(0, jnp.int32), jnp.zeros(0, out_dtype)
+        if int(l.min()) < 0 or int(r.max()) > _INT32_MAX:
+            raise ValueError(
+                f"query bounds [{int(l.min())}, {int(r.max())}] outside the engines' "
+                "int32 index range"
+            )
+        short = (r - l + 1) <= threshold
+        n_short = int(short.sum())
+        cb = getattr(_split_sink, "cb", None)
+        if cb is not None:
+            cb(n_short, int(l.size - n_short))
+        # Uniform batches skip the partition/scatter round-trip entirely.
+        if n_short == short.size or n_short == 0:
+            subs = [(short_fn if n_short else long_fn, None, *_pad_pow2(l, r))]
+        else:
+            subs = [
+                (fn, mask, *_pad_pow2(l[mask], r[mask]))
+                for mask, fn in ((short, short_fn), (~short, long_fn))
+            ]
+    # Each sub-batch launches as soon as its bounds are on the device, so
+    # the first computes while the second's bounds travel.
+    outs = []
+    for fn, _, lp, rp, _ in subs:
+        with tr.span("h2d"):
+            lj, rj = jnp.asarray(lp), jnp.asarray(rp)
+        with tr.span("enqueue"):
+            outs.append(fn(lj, rj))
+    if len(subs) == 1:
+        (qi, qv), k = outs[0], subs[0][4]
         return qi[:k], qv[:k]
 
-    # Mixed batch: launch both sub-batches, then sync both — overlapping the
-    # two engines' execution with a single wait.
+    # Mixed batch: each sub-batch's answers come back and are scattered into
+    # batch order as soon as they are ready, the first while the second runs.
     idx = np.empty(l.shape, np.int32)
     val = np.empty(l.shape, np.dtype(out_dtype))
-    launched = []
-    for mask, fn in ((short, short_fn), (~short, long_fn)):
-        launched.append((mask, _launch(fn, l[mask], r[mask])))
-    for mask, (qi, qv, k) in launched:
-        idx[mask] = np.asarray(qi)[:k]
-        val[mask] = np.asarray(qv)[:k]
-    return jnp.asarray(idx), jnp.asarray(val)
+    for (_, mask, _, _, k), (qi, qv) in zip(subs, outs):
+        with tr.span("wait"):
+            jax.block_until_ready((qi, qv))
+        with tr.span("merge"):
+            idx[mask] = np.asarray(qi)[:k]
+            val[mask] = np.asarray(qv)[:k]
+    with tr.span("merge"):
+        return jnp.asarray(idx), jnp.asarray(val)
 
 
 def query(s: HybridRMQ, l, r) -> Tuple[jax.Array, jax.Array]:

@@ -5,7 +5,8 @@ A **span** is one named, timed unit of work: monotonic start/end stamps
 small key/value attr dict. Spans form trees — the serving layer opens a
 ``request`` root per client request and hangs ``admission``/``queue``/
 ``resolve`` children off it, the batcher opens a ``flush`` root per
-coalesced launch with ``coalesce``/``launch``/``scatter`` children, and the
+coalesced launch with ``coalesce``/``launch``/``wait``/``d2h``/``scatter``/
+``finish`` children, and the
 build/update pipelines ride the ``core.build.run_stages`` sequencer so every
 stage (``local_build``, ``apply_deltas``, ``publish``, ...) lands as a span
 under whatever was current. Cross-thread parenting is explicit (pass
@@ -27,6 +28,19 @@ Design constraints, in order:
    thread-name metadata) that chrome://tracing and https://ui.perfetto.dev
    open directly; span/parent ids ride in ``args`` so the request chains
    survive the export.
+4. **One clock with the device.** An enabled tracer mirrors every span it
+   opens through ``span()`` as a ``jax.profiler.TraceAnnotation`` named
+   ``rmq.<name>`` on the opening thread, so under a profiler session the
+   span lands in the host plane of the ``.xplane.pb`` beside the device's
+   ops, on the profiler's own clock. Spans opened with ``start()`` and
+   finished elsewhere (``request``, ``queue``, ``flush``) cross threads and
+   stay in the ring buffer only.
+5. **Process hooks.** While an enabled tracer is the global one
+   (``set_tracer``) it holds one ``gc.callbacks`` hook, recording a ``gc``
+   span per collection (attrs ``generation``, ``collected``; mirrored as
+   ``rmq.gc`` on the collecting thread), and one ``jax.monitoring``
+   duration listener, recording a ``compile`` span per lowering of a jaxpr
+   (``COMPILE_EVENT``). Swapping the tracer out removes both.
 
 ``verify_request_chains`` is the acceptance-side consumer: it walks an
 exported (or live) span set and checks that every successfully resolved
@@ -37,6 +51,7 @@ check.sh's observability gate and ``launch/serve.py --trace`` both call it.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import threading
@@ -46,6 +61,8 @@ from time import perf_counter
 from typing import Dict, Iterable, List, Optional, Tuple
 
 __all__ = [
+    "COMPILE_EVENT",
+    "HOOK_SPANS",
     "NULL_TRACER",
     "Span",
     "Tracer",
@@ -55,6 +72,11 @@ __all__ = [
     "set_tracer",
     "verify_request_chains",
 ]
+
+# The spans an enabled global tracer records from process hooks, and the
+# jax.monitoring event that marks one compile (the lowering of a jaxpr).
+HOOK_SPANS = ("gc", "compile")
+COMPILE_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 _ids = itertools.count(1)
 _CURRENT: ContextVar[Optional["Span"]] = ContextVar("repro_obs_span", default=None)
@@ -122,16 +144,19 @@ _NOOP_CTX = _NoopCtx()
 
 class _SpanCtx:
     """Context manager for one live span: finishes it and restores the
-    ambient current span on exit (same-thread nesting)."""
+    ambient current span on exit (same-thread nesting), mirrored by a
+    profiler annotation ``rmq.<name>`` over the same stretch."""
 
-    __slots__ = ("_tracer", "_span", "_token")
+    __slots__ = ("_tracer", "_span", "_token", "_ann")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self._span = span
         self._token = None
+        self._ann = tracer._annotation("rmq." + span.name)
 
     def __enter__(self) -> Span:
+        self._ann.__enter__()
         self._token = _CURRENT.set(self._span)
         return self._span
 
@@ -140,6 +165,7 @@ class _SpanCtx:
             self._span.attrs.setdefault("error", exc_type.__name__)
         _CURRENT.reset(self._token)
         self._tracer.finish(self._span)
+        self._ann.__exit__(None, None, None)
         return False
 
 
@@ -155,10 +181,17 @@ class Tracer:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.enabled = bool(enabled)
         self.capacity = int(capacity)
-        self._lock = threading.Lock()
+        # Re-entrant: a gc hook may fire while this thread holds the lock.
+        self._lock = threading.RLock()
         self._buf: deque = deque(maxlen=self.capacity)
         self._dropped = 0
         self._t_epoch = perf_counter()  # export time origin
+        self._gc_open = None  # (t0, annotation) of the collection under way
+        self._annotation = None
+        if self.enabled:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
 
     # -- recording ----------------------------------------------------------
 
@@ -203,6 +236,44 @@ class Tracer:
         s = self.start(name, parent=parent, attrs=attrs)
         self.finish(s)
         return s
+
+    # -- process hooks (installed by set_tracer) -------------------------------
+
+    def _hook(self) -> None:
+        import jax
+
+        gc.callbacks.append(self._on_gc)
+        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+
+    def _unhook(self) -> None:
+        import jax
+
+        gc.callbacks.remove(self._on_gc)
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
+        self._gc_open = None
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            ann = self._annotation("rmq.gc")
+            ann.__enter__()
+            self._gc_open = (perf_counter(), ann)
+        elif self._gc_open is not None:
+            t0, ann = self._gc_open
+            self._gc_open = None
+            ann.__exit__(None, None, None)
+            self._record(
+                "gc", t0, {"generation": info["generation"], "collected": info["collected"]}
+            )
+
+    def _on_duration(self, event: str, duration: float, **_kw) -> None:
+        if event == COMPILE_EVENT:
+            self._record("compile", perf_counter() - duration, {"seconds": duration})
+
+    def _record(self, name: str, t0: float, attrs: dict) -> None:
+        """Commit a root span that ended now and began at ``t0``."""
+        s = Span(name, None, attrs)
+        s.t0 = t0
+        self.finish(s)
 
     # -- introspection / export ---------------------------------------------
 
@@ -279,11 +350,17 @@ def get_tracer() -> Tracer:
 
 def set_tracer(tracer: Optional[Tracer]) -> Tracer:
     """Install ``tracer`` globally (None restores the disabled singleton);
-    returns the previous global so callers/tests can restore it."""
+    returns the previous global so callers/tests can restore it. The
+    global tracer, when enabled, holds the process hooks: the previous
+    one's are removed, the new one's installed."""
     global _GLOBAL
     with _GLOBAL_LOCK:
         prev = _GLOBAL
+        if prev.enabled:
+            prev._unhook()
         _GLOBAL = tracer if tracer is not None else NULL_TRACER
+        if _GLOBAL.enabled:
+            _GLOBAL._hook()
         return prev
 
 
@@ -294,7 +371,7 @@ def current_span() -> Optional[Span]:
 
 def set_attr(key: str, value) -> None:
     """Annotate the ambient span, if any — the seam engine internals use
-    (e.g. ``hybrid.dispatch_by_length`` stamping its regime split) without
+    (e.g. ``OnlineEngine.apply`` stamping its write counts) without
     holding a tracer reference. No-op when nothing is current."""
     cur = _CURRENT.get()
     if cur is not None:

@@ -57,6 +57,7 @@ from concurrent.futures import Future
 from dataclasses import dataclass
 from typing import Callable, List, NamedTuple, Optional, Sequence, Set, Tuple
 
+import jax
 import numpy as np
 
 from repro.core import hybrid as _hybrid
@@ -737,8 +738,8 @@ class RMQServer:
                         )
                     if not pending:
                         return
-            # The flush span is this batch's root: coalesce/launch/scatter
-            # hang off it, and every member request links to it via its
+            # The flush span is this batch's root: coalesce and the worker's
+            # launch/wait/d2h/scatter/finish hang off it, and every member request links to it via its
             # "batch" attr. It travels to the worker and finishes there.
             fs = None
             if tr.enabled:
@@ -904,6 +905,7 @@ class RMQServer:
             if splits and tr.enabled and lsp is not None:
                 lsp.set_attr("short", sum(s for s, _ in splits))
                 lsp.set_attr("long", sum(g for _, g in splits))
+            idx, val = self._to_host(fs, idx, val)
             with tr.span("scatter", parent=fs):
                 parts = scatter_back(mb, idx, val)
         except BaseException:
@@ -930,9 +932,19 @@ class RMQServer:
             else:  # unreachable: __init__ validates breaker => degraded path
                 raise EngineFailure("breaker open and no fallback", retryable=False)
         self._h_launch["degraded"].observe(time.perf_counter() - t0)
+        idx, val = self._to_host(fs, idx, val)
         with tr.span("scatter", parent=fs):
             parts = scatter_back(mb, idx, val)
         return parts, [], True
+
+    def _to_host(self, fs, idx, val):
+        """A launch's results on the host: ``wait`` for the device, then
+        copy (``d2h``), each a span under flush span ``fs``."""
+        tr = self._tracer
+        with tr.span("wait", parent=fs):
+            jax.block_until_ready((idx, val))
+        with tr.span("d2h", parent=fs):
+            return np.asarray(idx), np.asarray(val)
 
     # -- circuit breaker ------------------------------------------------------
 
@@ -1065,49 +1077,52 @@ class RMQServer:
             self._fail_future(q, exc)
 
     def _finish(self, mb: MicroBatch, reqs, ver, fs, parts, splits, degraded: bool):
+        """Account a served launch and resolve its requests, in span
+        ``finish``: the last child of flush span ``fs``, which ends after it."""
         tr = self._tracer
-        lag = 0
-        if ver is not None:
-            lag = self._online.current_vid - ver.vid
-            self._online.release(ver.vid)
-        t_done = time.perf_counter()
-        if fs is not None:
+        with tr.span("finish", parent=fs):
+            lag = 0
             if ver is not None:
+                lag = self._online.current_vid - ver.vid
+                self._online.release(ver.vid)
+            t_done = time.perf_counter()
+            if fs is not None and ver is not None:
                 fs.set_attr("lag", lag)
-            tr.finish(fs)
-        with self._lock:
-            self._inflight -= len(reqs)
-            self._g_inflight.set(self._inflight)
-            self._splits.extend(splits)
-            self._padded.add(mb.padded_size)
-            if ver is not None:
-                self._lags.append(lag)
-                self._g_vlag.set(lag)
-            for q in reqs:
-                self._live.discard(q)
-            self._t_last_done = t_done
-        self._m_batches.inc()
-        self._m_queries.inc(int(mb.n_queries))
-        self._m_out["served"].inc(len(reqs))
-        for s, g in splits:
-            self._m_regime["short"].inc(s)
-            self._m_regime["long"].inc(g)
-        for q, (qi, qv) in zip(reqs, parts):
-            self._h_queue.observe(q.t_flush - q.t_submit)
-            self._h_service.observe(t_done - q.t_flush)
-            self._h_total.observe(t_done - q.t_submit)
-            self._trace_resolve(q, "ok")
-            try:
-                q.future.set_result(
-                    RequestResult(
-                        qi,
-                        qv,
-                        RequestTiming(q.t_flush - q.t_submit, t_done - q.t_flush, t_done - q.t_submit),
-                        ver.vid if ver is not None else None,
+            with self._lock:
+                self._inflight -= len(reqs)
+                self._g_inflight.set(self._inflight)
+                self._splits.extend(splits)
+                self._padded.add(mb.padded_size)
+                if ver is not None:
+                    self._lags.append(lag)
+                    self._g_vlag.set(lag)
+                for q in reqs:
+                    self._live.discard(q)
+                self._t_last_done = t_done
+            self._m_batches.inc()
+            self._m_queries.inc(int(mb.n_queries))
+            self._m_out["served"].inc(len(reqs))
+            for s, g in splits:
+                self._m_regime["short"].inc(s)
+                self._m_regime["long"].inc(g)
+            for q, (qi, qv) in zip(reqs, parts):
+                self._h_queue.observe(q.t_flush - q.t_submit)
+                self._h_service.observe(t_done - q.t_flush)
+                self._h_total.observe(t_done - q.t_submit)
+                self._trace_resolve(q, "ok")
+                try:
+                    q.future.set_result(
+                        RequestResult(
+                            qi,
+                            qv,
+                            RequestTiming(q.t_flush - q.t_submit, t_done - q.t_flush, t_done - q.t_submit),
+                            ver.vid if ver is not None else None,
+                        )
                     )
-                )
-            except Exception:
-                pass  # already failed (expired/closed): result has no taker
+                except Exception:
+                    pass  # already failed (expired/closed): result has no taker
+        if fs is not None:
+            tr.finish(fs)
 
     def _trace_resolve(self, q, outcome: str):
         """Terminal span bookkeeping for one request: close any open queue

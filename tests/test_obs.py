@@ -3,15 +3,21 @@
 Covers the DESIGN.md §14 contract directly: same-thread ambient nesting and
 explicit cross-thread parenting, ring-buffer overflow keeping the newest
 spans, the disabled tracer allocating nothing on the hot path (tracemalloc
-probe), Chrome-trace JSON schema, exact histogram percentiles, and — end to
-end through a real threaded ``RMQServer`` — that every served request exports
-a complete span chain and that the metrics registry exactly reconciles with
-the ``ServeStats`` snapshot rendered from it.
+probe, the hybrid dispatch's spans included), Chrome-trace JSON schema,
+exact histogram percentiles; the profiler mirror of enabled spans and the
+gc/compile hooks a global tracer holds; and — end to end through a real
+threaded ``RMQServer`` — that every served request exports a complete span
+chain, that each launch spans its cycle (dispatch phases under ``launch``;
+``wait``/``d2h``/``scatter``/``finish`` under ``flush``), and that the
+metrics registry exactly reconciles with the ``ServeStats`` snapshot
+rendered from it.
 """
 
+import gc
 import json
 import threading
 import tracemalloc
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -41,6 +47,13 @@ def tracer():
         set_tracer(prev)
 
 
+@pytest.fixture
+def local_tracer():
+    """A fresh enabled tracer that is not the global one, so it holds no
+    process hooks: it records exactly the spans a test opens."""
+    return Tracer(enabled=True, capacity=4096)
+
+
 def _oracle_engine(x):
     def qfn(l, r):
         idx = ref.rmq_ref(x, l, r).astype(np.int32)
@@ -52,7 +65,8 @@ def _oracle_engine(x):
 # --- tracer core ------------------------------------------------------------
 
 
-def test_span_ambient_nesting_same_thread(tracer):
+def test_span_ambient_nesting_same_thread(local_tracer):
+    tracer = local_tracer
     with tracer.span("outer") as outer:
         assert current_span() is outer
         with tracer.span("inner") as inner:
@@ -105,7 +119,8 @@ def test_ring_buffer_overflow_keeps_newest():
     assert t.spans() == [] and t.dropped == 0
 
 
-def test_span_ctx_records_error_attr(tracer):
+def test_span_ctx_records_error_attr(local_tracer):
+    tracer = local_tracer
     with pytest.raises(ValueError):
         with tracer.span("launch"):
             raise ValueError("boom")
@@ -121,23 +136,42 @@ def test_set_attr_noop_outside_span(tracer):
     assert sp.attrs == {"k": 2}
 
 
+def _numpy_path(l, r):
+    """A stand-in engine path: answers on the host, no device."""
+    return np.asarray(l, np.int32), np.asarray(r, np.float32)
+
+
 def test_disabled_tracer_allocates_nothing():
+    from repro.core import hybrid
+
     t = NULL_TRACER
+    assert obs_trace.get_tracer() is NULL_TRACER
+    l = np.arange(64, dtype=np.int32)
+    uniform, mixed = l + 1, np.where(l % 2 == 0, l + 1, 63)
+
+    def dispatch(r):
+        hybrid.dispatch_by_length(l, r, 8, _numpy_path, _numpy_path, np.float32)
+
     # Warm every code path once, then assert the steady state is alloc-free.
     with t.span("x"):
         pass
     t.start("x")
     t.instant("x")
+    dispatch(uniform)
+    dispatch(mixed)
     tracemalloc.start()
     try:
         before = tracemalloc.take_snapshot()
-        for _ in range(200):
+        for i in range(200):
             with t.span("hot"):
                 pass
             s = t.start("hot")
             s.set_attr("k", 1)
             t.finish(s)
             t.instant("hot")
+            if i % 10 == 0:  # prepare/h2d/enqueue, and wait/merge when mixed
+                dispatch(uniform)
+                dispatch(mixed)
         after = tracemalloc.take_snapshot()
     finally:
         tracemalloc.stop()
@@ -149,7 +183,8 @@ def test_disabled_tracer_allocates_nothing():
     assert growth == 0, f"disabled tracer allocated {growth} bytes"
 
 
-def test_chrome_trace_export_schema(tracer, tmp_path):
+def test_chrome_trace_export_schema(local_tracer, tmp_path):
+    tracer = local_tracer
     with tracer.span("flush", attrs={"reason": "size"}):
         with tracer.span("launch", attrs={"engine": "hybrid", "cfg": object()}):
             pass
@@ -172,6 +207,86 @@ def test_chrome_trace_export_schema(tracer, tmp_path):
     assert launch["args"]["engine"] == "hybrid"
     assert isinstance(launch["args"]["cfg"], str)  # non-scalar attrs stringified
     assert ms and all(e["args"]["name"] for e in ms)  # thread names labelled
+
+
+# --- the profiler's clock and the process hooks -----------------------------
+
+
+def _host_event_names(prof_dir: Path) -> list:
+    import jax
+
+    (path,) = sorted(prof_dir.rglob("*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    return [
+        e.name
+        for plane in pd.planes
+        if plane.name.startswith("/host")
+        for line in plane.lines
+        for e in line.events
+    ]
+
+
+def test_enabled_spans_land_in_the_profiler_trace(local_tracer, tmp_path):
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with local_tracer.span("x"):
+            with local_tracer.span("y"):
+                pass
+        root = local_tracer.start("flush", parent=0)  # crosses threads: buffer only
+        local_tracer.finish(root)
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_event_names(tmp_path)
+    assert names.count("rmq.x") == 1 and names.count("rmq.y") == 1
+    assert "rmq.flush" not in names
+    assert [s.name for s in local_tracer.spans()] == ["y", "x", "flush"]
+
+
+def _fresh_compile():
+    import jax
+    import jax.numpy as jnp
+
+    jax.jit(lambda v: v * 3 + 1)(jnp.arange(3.0)).block_until_ready()
+
+
+def test_global_tracer_records_gc_and_compile_spans(tracer):
+    tracer.clear()
+    gc.collect()
+    full = [s for s in tracer.spans() if s.name == "gc" and s.attrs["generation"] == 2]
+    assert len(full) == 1
+    assert full[0].parent_id is None and full[0].t1 >= full[0].t0
+    assert isinstance(full[0].attrs["collected"], int)
+    _fresh_compile()
+    compiles = [s for s in tracer.spans() if s.name == "compile"]
+    assert compiles
+    for s in compiles:
+        assert s.t1 - s.t0 == pytest.approx(s.attrs["seconds"], abs=1e-3)
+    assert set(obs_trace.HOOK_SPANS) == {"gc", "compile"}
+
+
+def test_swapping_the_tracer_out_removes_its_hooks():
+    prev = set_tracer(None)
+    base = len(gc.callbacks)
+    first, second = Tracer(enabled=True), Tracer(enabled=True)
+    try:
+        set_tracer(first)
+        assert len(gc.callbacks) == base + 1
+        set_tracer(second)  # a swap moves the one hook to the new tracer
+        assert len(gc.callbacks) == base + 1
+        gc.collect()
+        _fresh_compile()
+        assert not [s for s in first.spans() if s.name in obs_trace.HOOK_SPANS]
+        assert {"gc", "compile"} <= {s.name for s in second.spans()}
+        set_tracer(None)
+        assert len(gc.callbacks) == base
+        second.clear()
+        gc.collect()
+        _fresh_compile()
+        assert second.spans() == []
+    finally:
+        set_tracer(prev)
 
 
 # --- metrics registry -------------------------------------------------------
@@ -266,6 +381,42 @@ def test_server_exports_complete_request_chains(tracer):
     launches = [s for s in tracer.spans() if s.name == "launch"]
     assert launches and all("engine" in s.attrs and "pool" in s.attrs for s in launches)
     del srv
+
+
+def test_served_hybrid_launches_span_their_cycle(tracer):
+    import jax.numpy as jnp
+
+    from repro.core import hybrid
+
+    n = 4096
+    x = np.random.default_rng(3).random(n).astype(np.float32)
+    state = hybrid.build(jnp.asarray(x), threshold=64)
+    srv = RMQServer(lambda l, r: hybrid.query(state, l, r), ServeConfig(n=n, max_batch=64, workers=1))
+    l = np.arange(0, 2048, 64)
+    batches = {"uniform": l + 10, "mixed": np.where(np.arange(l.size) % 2 == 0, l + 10, l + 1000)}
+    with srv:
+        for r in batches.values():  # one request per flush
+            res = srv.submit(l, r).result(timeout=120)
+            np.testing.assert_array_equal(res.idx, ref.rmq_ref(x, l, r))
+    spans = tracer.spans()
+    assert verify_request_chains(spans) == (2, [])
+    kids = {}
+    for s in spans:
+        kids.setdefault(s.parent_id, []).append(s)
+    flushes = sorted((s for s in spans if s.name == "flush"), key=lambda s: s.t0)
+    assert len(flushes) == 2
+    for fs, kind in zip(flushes, batches):
+        cycle = [s.name for s in sorted(kids[fs.span_id], key=lambda s: s.t0)]
+        assert cycle == ["coalesce", "launch", "wait", "d2h", "scatter", "finish"]
+        (launch,) = [s for s in kids[fs.span_id] if s.name == "launch"]
+        phases = [s.name for s in sorted(kids[launch.span_id], key=lambda s: s.t0)]
+        if kind == "mixed":  # each half launches, then comes back, in turn
+            assert phases == ["prepare"] + ["h2d", "enqueue"] * 2 + ["wait", "merge"] * 2 + ["merge"]
+        else:
+            assert phases == ["prepare", "h2d", "enqueue"]
+        assert "split_short" not in launch.attrs and "short" in launch.attrs
+        finish = next(s for s in kids[fs.span_id] if s.name == "finish")
+        assert fs.t1 >= finish.t1  # the flush ends after its last child
 
 
 def test_verify_request_chains_flags_gaps(tracer):
