@@ -159,13 +159,18 @@ def dispatch_by_length(l, r, threshold: int, short_fn, long_fn, out_dtype):
     would wrap silently instead of failing loudly — checked here, the one
     query path both hybrids share.
 
+    Answers are returned where they already are: a uniform batch's as the
+    launch's device arrays (the caller copies them once), a mixed batch's
+    as host ``np.ndarray``s (int32 and ``out_dtype``), since the merge
+    scatters both halves into batch order on the host and sending the
+    result back to the device would only be copied out again.
+
     Each phase is a span under the caller's ambient one (the server's
     ``launch``): ``prepare`` (checks, casts, partition, padding), then per
     sub-batch ``h2d`` (its bounds to the device) and ``enqueue`` (its
     asynchronous launch), and on a mixed batch per sub-batch ``wait`` (on
     its launch) and ``merge`` (its answers to the host, scattered into
-    batch order), with a last ``merge`` (the whole answer back to the
-    device).
+    batch order).
     """
     tr = obs_trace.get_tracer()
     with tr.span("prepare"):
@@ -196,13 +201,20 @@ def dispatch_by_length(l, r, threshold: int, short_fn, long_fn, out_dtype):
                 for mask, fn in ((short, short_fn), (~short, long_fn))
             ]
     # Each sub-batch launches as soon as its bounds are on the device, so
-    # the first computes while the second's bounds travel.
+    # the first computes while the second's bounds travel. A mixed batch's
+    # answers are copied to the host as soon as their launch ends, not when
+    # the merge gets to them.
     outs = []
-    for fn, _, lp, rp, _ in subs:
+    for fn, mask, lp, rp, _ in subs:
         with tr.span("h2d"):
             lj, rj = jnp.asarray(lp), jnp.asarray(rp)
         with tr.span("enqueue"):
-            outs.append(fn(lj, rj))
+            out = fn(lj, rj)
+            if mask is not None:
+                for a in out:
+                    if isinstance(a, jax.Array):
+                        a.copy_to_host_async()
+            outs.append(out)
     if len(subs) == 1:
         (qi, qv), k = outs[0], subs[0][4]
         return qi[:k], qv[:k]
@@ -217,15 +229,16 @@ def dispatch_by_length(l, r, threshold: int, short_fn, long_fn, out_dtype):
         with tr.span("merge"):
             idx[mask] = np.asarray(qi)[:k]
             val[mask] = np.asarray(qv)[:k]
-    with tr.span("merge"):
-        return jnp.asarray(idx), jnp.asarray(val)
+    return idx, val
 
 
-def query(s: HybridRMQ, l, r) -> Tuple[jax.Array, jax.Array]:
+def query(s: HybridRMQ, l, r) -> Tuple[jax.Array | np.ndarray, jax.Array | np.ndarray]:
     """Range-adaptive batched RMQ. Returns (leftmost argmin idx int32, value).
 
     Host-side partition by range length, per-engine sub-batches, ordered
     scatter-back. Bit-identical to ``block_rmq.query`` on the same batch.
+    A uniform batch's answers are device arrays, a mixed batch's host
+    ``np.ndarray``s (``dispatch_by_length``).
     """
     return dispatch_by_length(l, r, s.threshold, s.short_fn, s.long_fn, s.x.dtype)
 

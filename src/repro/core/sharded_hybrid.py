@@ -53,6 +53,7 @@ from __future__ import annotations
 from typing import NamedTuple, Sequence, Tuple
 
 import jax
+import numpy as np
 
 from .hybrid import dispatch_by_length
 
@@ -112,14 +113,16 @@ def build(
     )
 
 
-def query(s: ShardedHybridRMQ, l, r) -> Tuple[jax.Array, jax.Array]:
+def query(s: ShardedHybridRMQ, l, r) -> Tuple[jax.Array | np.ndarray, jax.Array | np.ndarray]:
     """Range-adaptive distributed batched RMQ -> (leftmost idx int32, value).
 
     Host-side partition by range length, per-regime *sharded* launches,
     ordered scatter-back — ``hybrid.dispatch_by_length`` with the sharded
     constituents closed over their states. (The batch-sharded query fns pad
     to a shard multiple internally, so divisibility is not this layer's
-    concern.) Bit-identical to ``block_rmq.query``.
+    concern.) Bit-identical to ``block_rmq.query``. A uniform batch's
+    answers are device arrays, a mixed batch's host ``np.ndarray``s merged
+    on the host.
     """
     return dispatch_by_length(
         l,

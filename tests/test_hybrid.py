@@ -1,10 +1,12 @@
 """Hybrid dispatcher: bit-identical results + correct scatter-back ordering."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import block_rmq, hybrid, ref
+from repro.core import block_rmq, hybrid, ref, sharded_hybrid
+from repro.launch.mesh import make_mesh
 
 
 def _mixed_batch(rng, n, b, threshold):
@@ -93,3 +95,40 @@ def test_threshold_default_and_calibrate_smoke():
     # 0 (all-long) and 4096 (all-short) are honest degenerate measurements.
     thr = hybrid.calibrate(4096, batch=256, use_kernels=False, repeats=1)
     assert 0 <= thr <= 4096
+
+
+def _batch(rng, n, b, threshold, kind):
+    """``b`` ranges, all short (<= threshold), all long, or both interleaved."""
+    if kind == "mixed":
+        return _mixed_batch(rng, n, b, threshold)
+    lo, hi = (1, threshold) if kind == "uniform_short" else (threshold + 1, n)
+    length = rng.integers(lo, hi + 1, b)
+    l = rng.integers(0, np.maximum(n - length + 1, 1), b)
+    return l, np.minimum(l + length - 1, n - 1)
+
+
+@pytest.mark.parametrize("kind", ["uniform_short", "uniform_long", "mixed"])
+@pytest.mark.parametrize("engine", ["hybrid", "sharded_hybrid"])
+def test_answers_come_back_where_they_were_merged(engine, kind, rng):
+    """Bit-identical to the oracle either way; a mixed batch's answers are
+    merged on the host and returned there (never sent back to the device),
+    a uniform batch's are the launch's own device arrays."""
+    n, threshold = 2048, 64
+    x = rng.integers(0, 11, n).astype(np.float32)  # dense ties: leftmost rule
+    if engine == "hybrid":
+        s = hybrid.build(jnp.asarray(x), 128, use_kernels=False, threshold=threshold)
+        query = hybrid.query
+    else:  # a one-device mesh, as the conformance suite builds it
+        mesh = make_mesh((1,), ("shard",))
+        s = sharded_hybrid.build(jnp.asarray(x), mesh, ("shard",), 128, threshold=threshold)
+        query = sharded_hybrid.query
+    l, r = _batch(rng, n, 200, threshold, kind)
+    idx, val = query(s, l, r)
+    gold = ref.rmq_ref(x, l, r)
+    np.testing.assert_array_equal(np.asarray(idx), gold)
+    np.testing.assert_array_equal(np.asarray(val), x[gold])
+    assert idx.dtype == np.int32 and val.dtype == np.float32
+    if kind == "mixed":
+        assert type(idx) is np.ndarray and type(val) is np.ndarray
+    else:
+        assert isinstance(idx, jax.Array) and isinstance(val, jax.Array)
