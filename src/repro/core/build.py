@@ -49,8 +49,10 @@ __all__ = [
     "ShardLayout",
     "build",
     "default_mesh",
+    "device_bytes_limit",
     "execute",
     "execute_update",
+    "hybrid_table_bytes",
     "plan_for",
     "planner_names",
     "run_stages",
@@ -140,7 +142,7 @@ def _resolve_threshold(
     from . import hybrid  # deferred: hybrid lowers its build through here
 
     if threshold is None:
-        return max(1, int(round(n**hybrid.DEFAULT_THRESHOLD_FRAC)))
+        return hybrid.regime_boundary(n)
     if isinstance(threshold, (int, np.integer)):
         return int(threshold)
     if threshold == "cached":
@@ -155,7 +157,7 @@ def _resolve_threshold(
         hit = calib_cache.load(key, path=cache_path)
         if hit is not None:
             return hit
-        return max(1, int(round(n**hybrid.DEFAULT_THRESHOLD_FRAC)))
+        return hybrid.regime_boundary(n)
     if threshold == "calibrated":
         return calib_cache.get_threshold(
             n,
@@ -220,6 +222,38 @@ def _norm_packed(packed) -> Optional[str]:
             f"a bool, or None; got {packed!r}"
         )
     return packed
+
+
+def device_bytes_limit() -> Optional[int]:
+    """The memory the first device holds (``memory_stats()["bytes_limit"]``),
+    or None where the backend reports none (the CPU)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return None if limit is None else int(limit)
+
+
+# Widest value the kernels serve (float32, int32): the plan sees n, not x.
+_VALUE_BYTES = 4
+
+
+def hybrid_table_bytes(n: int, *, word_bytes: int = 4, block_size: int = 128) -> int:
+    """Device bytes a hybrid build holds at its peak when it builds the
+    doubling table over the raw array, ``x`` included.
+
+    The table is ``sparse_table.levels(n)`` rows of ``n`` words, its rows
+    padded to a multiple of 8 as a TPU lays them out (27 -> 32 rows at
+    n = 2^26: 8 GiB). Beside it: ``x`` and its padded block copy, the three
+    planes of the kernel's table over block minima and the minima
+    themselves, and the table build's transient, three length-``n`` rows
+    (its copy of ``x`` and two rows of temporaries: v5e compile).
+    """
+    nb = -(-n // block_size)
+    nbp = -(-nb // 128) * 128
+    pad8 = lambda rows: -(-rows // 8) * 8  # noqa: E731
+    table = pad8(sparse_table.levels(n)) * n * word_bytes
+    blocks = 2 * nb * block_size * _VALUE_BYTES + 2 * nb * 4
+    block_tables = 3 * pad8(sparse_table.levels(nb)) * nbp * 4
+    return table + 3 * n * 4 + blocks + block_tables
 
 
 # --- pipeline execution -----------------------------------------------------
@@ -495,13 +529,33 @@ def _plan_hybrid(
         use_kernels = jax.default_backend() == "tpu"
     if use_kernels:
         _check_kernel_block_size(block_size)
-    thr = _resolve_threshold(
-        threshold,
-        n,
-        block_size,
-        calibrate_kw={"use_kernels": use_kernels},
-        layout=pack_layout,
-    )
+    # The one place that decides whether the doubling table over the raw
+    # array is built. Where the device reports its memory and the table does
+    # not fit beside the rest of the build, the kernel answers every range
+    # (its interior lookups use the table over block minima); the online
+    # hybrid (``use_kernels=False``) keeps its table.
+    long_path = "table"
+    limit = device_bytes_limit()
+    if limit is not None:
+        word = 8 if pack_layout == "packed64" else 4
+        if hybrid_table_bytes(n, word_bytes=word, block_size=block_size) > limit:
+            if pack_layout is not None:
+                raise ValueError(
+                    f"packed hybrid at n={n}: its {pack_layout} doubling table over "
+                    f"the raw array does not fit the device's {limit} bytes"
+                )
+            if use_kernels:
+                long_path = "kernel"
+    if long_path == "kernel":
+        thr = n
+    else:
+        thr = _resolve_threshold(
+            threshold,
+            n,
+            block_size,
+            calibrate_kw={"use_kernels": use_kernels},
+            layout=pack_layout,
+        )
     # The megakernel's launch geometry, swept within this build's block size
     # (the hybrid's structures are committed to it). Resolved only when the
     # short path actually runs the kernels.
@@ -536,7 +590,7 @@ def _plan_hybrid(
             state["blocked"] = kernels.ops.build(x, block_size)
         else:
             state["blocked"] = block_rmq.build(x, block_size)
-        state["st"] = sparse_table.build(x)
+        state["st"] = sparse_table.build(x) if long_path == "table" else None
         return state
 
     def fin(state):
@@ -563,7 +617,7 @@ def _plan_hybrid(
                 short_fn = functools.partial(kernels.ops.query, blocked, config=cfg)
             else:
                 short_fn = functools.partial(hybrid.block_query, blocked)
-            long_fn = functools.partial(hybrid.long_query, table, x)
+            long_fn = None if table is None else functools.partial(hybrid.long_query, table, x)
 
         state["result"] = hybrid.HybridRMQ(
             blocked=blocked,
@@ -590,6 +644,7 @@ def _plan_hybrid(
             "use_kernels": bool(use_kernels),
             "kernel_config": cfg,
             "packed": pack_layout,
+            "long_path": long_path,
         },
     )
 

@@ -323,7 +323,7 @@ def _flat_shift(x, mesh: Mesh, axis_names: Sequence[str], d: int):
 def st_levels(n_pad: int) -> int:
     """Doubling-table depth for a length-``n_pad`` array (matches
     ``sparse_table.build`` exactly — bit-identity depends on it)."""
-    return max(1, (n_pad - 1).bit_length() + 1) if n_pad > 1 else 1
+    return sparse_table.levels(n_pad)
 
 
 @functools.lru_cache(maxsize=None)

@@ -24,7 +24,7 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +43,7 @@ __all__ = [
     "dispatch_by_length",
     "record_splits",
     "DEFAULT_THRESHOLD_FRAC",
+    "regime_boundary",
 ]
 
 # Fallback threshold when no calibration is run: the paper's small/medium
@@ -53,16 +54,28 @@ DEFAULT_THRESHOLD_FRAC = 0.5  # threshold = n ** DEFAULT_THRESHOLD_FRAC
 _INT32_MAX = np.iinfo(np.int32).max
 
 
+def regime_boundary(n: int) -> int:
+    """The deterministic sqrt(n) threshold: ranges up to it are short."""
+    return max(1, int(round(n**DEFAULT_THRESHOLD_FRAC)))
+
+
 class HybridRMQ(NamedTuple):
-    """Both constituent structures, routing threshold, jitted path closures."""
+    """Both constituent structures, routing threshold, jitted path closures.
+
+    ``st`` and ``long_fn`` are None when the build planner found no room for
+    the doubling table over the raw array (``core.build``: the table is
+    ~32 words per element): the fused kernel then answers every range, the
+    long ones through its interior lookups on the table over block minima,
+    and ``threshold`` is ``n``.
+    """
 
     blocked: BlockRMQ
-    st: sparse_table.SparseTable  # doubling table over the raw array
+    st: Optional[sparse_table.SparseTable]  # doubling table over the raw array
     x: jax.Array  # raw values (answers value lookups for the long path)
     threshold: int  # range lengths <= threshold go to the blocked path
     use_kernels: bool  # short path: fused Pallas megakernel vs pure jnp
     short_fn: object  # jitted (l, r) -> (idx, val), structure closed over
-    long_fn: object  # jitted (l, r) -> (idx, val)
+    long_fn: object  # jitted (l, r) -> (idx, val); None without ``st``
 
 
 def build(
@@ -121,14 +134,16 @@ _split_sink = threading.local()
 
 
 @contextlib.contextmanager
-def record_splits(cb):
-    """Route this thread's ``dispatch_by_length`` splits to ``cb(n_short, n_long)``."""
-    prev = getattr(_split_sink, "cb", None)
-    _split_sink.cb = cb
+def record_splits(cb, kernel_long=None):
+    """Route this thread's ``dispatch_by_length`` splits to ``cb(n_short,
+    n_long)``, and, from a dispatch with no long path, the number of long
+    ranges its short path answered to ``kernel_long(n)``."""
+    prev = getattr(_split_sink, "cb", None), getattr(_split_sink, "kernel_long", None)
+    _split_sink.cb, _split_sink.kernel_long = cb, kernel_long
     try:
         yield
     finally:
-        _split_sink.cb = prev
+        _split_sink.cb, _split_sink.kernel_long = prev
 
 
 def _pad_pow2(lm: np.ndarray, rm: np.ndarray):
@@ -153,6 +168,9 @@ def dispatch_by_length(l, r, threshold: int, short_fn, long_fn, out_dtype):
     per-regime launches through ``short_fn`` / ``long_fn`` (each
     ``(l_jnp, r_jnp) -> (idx, val)``), ordered exact-leftmost scatter-back.
     Empty batches return empty ``(idx, val)`` without launching anything.
+    With ``long_fn=None`` nothing is partitioned: ``short_fn`` answers the
+    whole batch in one launch, and the ranges longer than ``threshold`` are
+    reported as the long ones it answered (``record_splits``).
 
     Bounds must be integer arrays inside the int32 index range: every
     constituent engine computes int32 indices, so an out-of-range bound
@@ -189,9 +207,15 @@ def dispatch_by_length(l, r, threshold: int, short_fn, long_fn, out_dtype):
             )
         short = (r - l + 1) <= threshold
         n_short = int(short.sum())
-        cb = getattr(_split_sink, "cb", None)
-        if cb is not None:
-            cb(n_short, int(l.size - n_short))
+        if long_fn is None:  # no long path: nothing to route
+            cb = getattr(_split_sink, "kernel_long", None)
+            if cb is not None:
+                cb(int(l.size - n_short))
+            n_short = short.size
+        else:
+            cb = getattr(_split_sink, "cb", None)
+            if cb is not None:
+                cb(n_short, int(l.size - n_short))
         # Uniform batches skip the partition/scatter round-trip entirely.
         if n_short == short.size or n_short == 0:
             subs = [(short_fn if n_short else long_fn, None, *_pad_pow2(l, r))]
@@ -238,9 +262,15 @@ def query(s: HybridRMQ, l, r) -> Tuple[jax.Array | np.ndarray, jax.Array | np.nd
     Host-side partition by range length, per-engine sub-batches, ordered
     scatter-back. Bit-identical to ``block_rmq.query`` on the same batch.
     A uniform batch's answers are device arrays, a mixed batch's host
-    ``np.ndarray``s (``dispatch_by_length``).
+    ``np.ndarray``s (``dispatch_by_length``). Without a long path the
+    kernel answers the whole batch; the sqrt(n) regime boundary then only
+    counts the long ranges among it.
     """
-    return dispatch_by_length(l, r, s.threshold, s.short_fn, s.long_fn, s.x.dtype)
+    if s.long_fn is None:
+        thr = regime_boundary(s.x.shape[0])
+    else:
+        thr = s.threshold
+    return dispatch_by_length(l, r, thr, s.short_fn, s.long_fn, s.x.dtype)
 
 
 def _measure(kind: str, fn, lj, rj, repeats: int) -> float:

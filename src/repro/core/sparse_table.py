@@ -24,6 +24,7 @@ __all__ = [
     "SparseTable",
     "build",
     "build_packed",
+    "levels",
     "query",
     "query_packed",
 ]
@@ -46,6 +47,11 @@ def _pick_left(x, a, b):
     return jnp.where(x[a] <= x[b], a, b)
 
 
+def levels(n: int) -> int:
+    """Rows of the doubling table over ``n`` elements."""
+    return max(1, (n - 1).bit_length() + 1) if n > 1 else 1
+
+
 @jax.jit
 def build(x: jax.Array) -> SparseTable:
     """Build the doubling table: one jitted loop over the K levels.
@@ -57,7 +63,7 @@ def build(x: jax.Array) -> SparseTable:
     what lets a TPU build the largest table its HBM holds.
     """
     n = x.shape[0]
-    k_levels = max(1, (n - 1).bit_length() + 1) if n > 1 else 1
+    k_levels = levels(n)
     cur = jnp.arange(n, dtype=jnp.int32)
     table = jnp.zeros((k_levels, n), jnp.int32).at[0].set(cur)
 
@@ -138,7 +144,7 @@ def build_packed(x: jax.Array, spec=None, layout: str = "auto"):
         t = build(x)
         words = packing.pack(spec, x[t.idx], t.idx)
         return PackedSparseTable(words=words, x=x), spec
-    k_levels = max(1, (n - 1).bit_length() + 1) if n > 1 else 1
+    k_levels = levels(n)
     cur = packing.pack(spec, x, jnp.arange(n, dtype=jnp.int32))
     rows = [cur]
     for k in range(1, k_levels):

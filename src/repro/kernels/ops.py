@@ -8,13 +8,15 @@ partials, sparse-table interior, and final merge — ``tile`` queries per grid
 step, with the launch geometry (tile, table fetch strategy) taken from a
 ``tuning.KernelConfig``. ``build`` returns a ``FusedRMQ``: the shared
 ``BlockRMQ`` fields plus the value-augmented doubling tables the DMA fetch
-strategy reads, precomputed once so the per-query jaxpr stays gather-free.
-The legacy two-pass path (partials kernel + XLA interior/merge) remains
-available via ``query(..., fused=False)`` for A/B benchmarking.
+strategy reads, precomputed once (in plain XLA) so the per-query jaxpr
+stays gather-free. The legacy two-pass path (partials kernel + XLA
+interior/merge) remains available via ``query(..., fused=False)`` for A/B
+benchmarking.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -23,7 +25,6 @@ import jax.numpy as jnp
 from repro.core import block_rmq, packing, sparse_table
 from repro.core.block_rmq import BlockRMQ, maxval, _pick
 
-from .block_min import block_min
 from .fused_query import DEFAULT_TILE, fused_query, fused_query_packed, interior_tables
 from .lane_query import lane_partials
 from .rmq_query import rmq_partials
@@ -36,7 +37,6 @@ __all__ = [
     "build_packed",
     "query",
     "query_packed",
-    "block_min",
     "fused_query",
     "fused_query_packed",
     "rmq_partials",
@@ -61,27 +61,28 @@ class FusedRMQ(NamedTuple):
     st_gidx: jax.Array  # bmin_gidx[st.idx] int32, windowed likewise
 
 
+@functools.partial(jax.jit, static_argnames=("block_size",))
+def _build(x: jax.Array, block_size: int) -> FusedRMQ:
+    b = block_rmq.build(x, block_size)
+    st_val, st_gidx = interior_tables(b.bmin_val, b.bmin_gidx, b.st.idx)
+    return FusedRMQ(*b, st_val=st_val, st_gidx=st_gidx)
+
+
 def build(x: jax.Array, block_size: int, *, interpret: bool | None = None) -> FusedRMQ:
-    """Kernelized build: Pallas per-block minima + doubling tables."""
+    """Kernel build: ``block_rmq.build``'s blocked structure plus the DMA
+    strategy's value-augmented doubling tables, in one jitted program.
+
+    One program so that the padded, reshaped copy of ``x`` is its only
+    full-size buffer: at n = 2^30 that copy is 4 GiB, and ``x`` stays alive
+    in the caller. The block minima are an XLA reduction over the
+    ``(nb, bs)`` rows, which keeps them lane-dense. ``interpret`` is
+    accepted for signature parity with the query; the build has no Pallas
+    stage.
+    """
+    del interpret
     if block_size % 128 != 0:
         raise ValueError(f"block_size must be a multiple of 128, got {block_size}")
-    n = x.shape[0]
-    nb = -(-n // block_size)
-    big = maxval(x.dtype)
-    xp = jnp.pad(x, (0, nb * block_size - n), constant_values=big)
-    xb = xp.reshape(nb, block_size)
-    bmin_val, lidx = block_min(xb, interpret=interpret)
-    bmin_gidx = jnp.arange(nb, dtype=jnp.int32) * block_size + lidx
-    st = sparse_table.build(bmin_val)
-    st_val, st_gidx = interior_tables(bmin_val, bmin_gidx, st.idx)
-    return FusedRMQ(
-        x_blocks=xb,
-        bmin_val=bmin_val,
-        bmin_gidx=bmin_gidx,
-        st=st,
-        st_val=st_val,
-        st_gidx=st_gidx,
-    )
+    return _build(x, block_size)
 
 
 class PackedFusedRMQ(NamedTuple):

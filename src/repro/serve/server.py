@@ -444,6 +444,9 @@ class RMQServer:
             reg: m.counter("serve_regime_queries_total", regime=reg)
             for reg in ("short", "long")
         }
+        # Ranges past the sqrt(n) regime boundary that the kernel answered
+        # because the hybrid was built without its long-path table.
+        self._m_kernel_long = m.counter("serve_kernel_long_queries_total")
         self._m_restarts = m.counter("serve_worker_restarts_total")
         self._m_trips = m.counter("serve_breaker_trips_total")
         self._m_updates = {
@@ -839,7 +842,7 @@ class RMQServer:
                 return
             mb, reqs, ver, fs = item
             try:
-                parts, splits, degraded = self._launch(mb, ver, fs)
+                parts, splits, kernel_long, degraded = self._launch(mb, ver, fs)
             except BaseException as e:
                 # Failed launch: its requests retry or fail — never the whole
                 # server. An injected crash additionally kills this worker
@@ -849,7 +852,7 @@ class RMQServer:
                 if isinstance(e, InjectedFault) and e.kind == "crash":
                     raise
                 continue
-            self._finish(mb, reqs, ver, fs, parts, splits, degraded)
+            self._finish(mb, reqs, ver, fs, parts, splits, kernel_long, degraded)
 
     def _launch_span(self, fs, ver, mb: MicroBatch, pool: str):
         """Context manager for one engine launch span under flush span ``fs``
@@ -863,7 +866,8 @@ class RMQServer:
         return self._tracer.span("launch", parent=fs, attrs=attrs)
 
     def _launch(self, mb: MicroBatch, ver, fs=None):
-        """One engine launch -> (per-request parts, regime splits, degraded?).
+        """One engine launch -> (per-request parts, regime splits, long
+        ranges the kernel answered, degraded?).
 
         Routes to the degraded fallback while the breaker is open; otherwise
         runs the primary engine, feeding the breaker's consecutive-failure
@@ -878,9 +882,10 @@ class RMQServer:
             # this launch: a thread-local sink, so concurrent workers
             # never see each other's splits.
             splits: List[Tuple[int, int]] = []
+            kernel_long: List[int] = []
             lsp = None
             t0 = time.perf_counter()
-            with _hybrid.record_splits(lambda s, g: splits.append((s, g))):
+            with _hybrid.record_splits(lambda s, g: splits.append((s, g)), kernel_long.append):
                 cm = self._launch_span(fs, ver, mb, "primary") if tr.enabled else tr.span("launch")
                 with cm as lsp:
                     if self._fault is not None:
@@ -912,7 +917,7 @@ class RMQServer:
             self._breaker_failure()
             raise
         self._breaker_success()
-        return parts, splits, False
+        return parts, splits, sum(kernel_long), False
 
     def _launch_degraded(self, mb: MicroBatch, ver, fs=None):
         """Answer via the correct-but-slower fallback path (breaker open)."""
@@ -935,7 +940,7 @@ class RMQServer:
         idx, val = self._to_host(fs, idx, val)
         with tr.span("scatter", parent=fs):
             parts = scatter_back(mb, idx, val)
-        return parts, [], True
+        return parts, [], 0, True
 
     def _to_host(self, fs, idx, val):
         """A launch's results on the host: ``wait`` for the device, then
@@ -1076,7 +1081,9 @@ class RMQServer:
             self._trace_resolve(q, "failed")
             self._fail_future(q, exc)
 
-    def _finish(self, mb: MicroBatch, reqs, ver, fs, parts, splits, degraded: bool):
+    def _finish(
+        self, mb: MicroBatch, reqs, ver, fs, parts, splits, kernel_long: int, degraded: bool
+    ):
         """Account a served launch and resolve its requests, in span
         ``finish``: the last child of flush span ``fs``, which ends after it."""
         tr = self._tracer
@@ -1105,6 +1112,7 @@ class RMQServer:
             for s, g in splits:
                 self._m_regime["short"].inc(s)
                 self._m_regime["long"].inc(g)
+            self._m_kernel_long.inc(kernel_long)
             for q, (qi, qv) in zip(reqs, parts):
                 self._h_queue.observe(q.t_flush - q.t_submit)
                 self._h_service.observe(t_done - q.t_flush)

@@ -25,7 +25,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from repro.core import distributed, packing, sparse_table
 from repro.kernels import ops
-from repro.kernels.block_min import block_min
 from repro.kernels.fused_query import fused_query, fused_query_packed
 from repro.kernels.lane_query import lane_partials
 from repro.kernels.rmq_query import rmq_partials
@@ -37,6 +36,9 @@ KERNEL_CALL = "tpu_custom_call"
 # shard_structure mode on the 2x2 mesh.
 N_ONE_CHIP = 1 << 26
 N_FOUR_CHIPS = 1 << 27
+# The largest power of two the int32 indices allow: the hybrid without its
+# table over the raw array, on one chip.
+N_TABLE_FREE = 1 << 30
 
 
 @pytest.fixture(scope="module")
@@ -131,11 +133,6 @@ def test_fused_query_packed_compiles(one_chip, layout):
         )
 
 
-def test_block_min_compiles(one_chip):
-    s, _, _ = _tables(one_chip, 1 << 19)
-    _compile(lambda x: block_min(x, interpret=False), s((1 << 19, 128), jnp.float32))
-
-
 def test_partials_kernels_compile(one_chip):
     nb = 1 << 13
     s, _, q = _tables(one_chip, nb)
@@ -171,11 +168,36 @@ def test_hybrid_build_fits_v5e(one_chip):
     (``ops.build``) and must fit with them. Rows are written into the table
     in place, so the build's temporaries stay a small share of the table."""
     x = jax.ShapeDtypeStruct((N_ONE_CHIP,), jnp.float32, sharding=one_chip)
-    blocked = _compile(lambda x: ops.build(x, 128, interpret=False), x).memory_analysis()
+    blocked = _compile(lambda x: ops.build(x, 128), x, kernel=False).memory_analysis()
     st = _compile(sparse_table.build, x, kernel=False).memory_analysis()
     assert st.output_size_in_bytes >= 8 << 30
     assert st.temp_size_in_bytes < st.output_size_in_bytes // 4
     assert _held(st) + blocked.output_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_table_free_hybrid_fits_v5e_at_2_30(one_chip):
+    """The hybrid at n = 2^30 on one chip, where its planner builds no table
+    over the raw array (it would be 128 GiB): the kernel build (``x``
+    stays alive as the argument) and the fused query at nb = 2^23, whose
+    interior lookups read (24, 2^16, 128) windowed tables, each fit one
+    chip with the structure resident."""
+    x = jax.ShapeDtypeStruct((N_TABLE_FREE,), jnp.float32, sharding=one_chip)
+    build = _compile(lambda x: ops.build(x, 128), x, kernel=False).memory_analysis()
+    assert build.argument_size_in_bytes == 4 << 30  # x, alive beside the build
+    assert build.temp_size_in_bytes < 256 << 20  # no second copy of x or its blocks
+    assert _held(build) < V5E_HBM_BYTES
+
+    built = jax.eval_shape(lambda x: ops.build(x, 128), x)
+    assert built.st_val.shape == (24, 1 << 16, 128)
+    built = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), built
+    )
+    q = jax.ShapeDtypeStruct((1 << 14,), jnp.int32, sharding=one_chip)
+    query = _compile(
+        lambda s, l, r: ops.query(s, l, r, interpret=False), built, q, q
+    ).memory_analysis()
+    assert query.alias_size_in_bytes == 0
+    assert _held(query) + build.argument_size_in_bytes < V5E_HBM_BYTES
 
 
 def test_sharded_hybrid_build_and_query_fit_four_v5e(four_chips):

@@ -118,9 +118,9 @@ def test_fused_dma_past_resident_ceiling():
     8x past the resident-table VMEM ceiling (the whole point of megakernel
     v2), through the single ``fused_query`` entry point.
 
-    The structure is built with the pure-jnp builder (the Pallas block_min
-    kernel's per-block grid would take minutes in interpret mode at this
-    size); the query path under test is exactly the megakernel.
+    The structure is built with the pure-jnp builder (the kernel build adds
+    only the augmented tables, derived here on the fly); the query path
+    under test is exactly the megakernel.
     """
     from repro.core import block_rmq
     from repro.kernels import tuning

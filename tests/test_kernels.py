@@ -7,7 +7,7 @@ import pytest
 
 from repro.core import ref as core_ref
 from repro.core.block_rmq import maxval
-from repro.kernels import block_min, ops, rmq_partials
+from repro.kernels import ops, rmq_partials
 from repro.kernels import ref as kref
 
 SHAPES = [(4, 128), (7, 128), (16, 256), (3, 512), (32, 128)]
@@ -17,22 +17,14 @@ DTYPES = [jnp.float32, jnp.int32, jnp.bfloat16]
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_block_min_kernel(shape, dtype, rng):
+    """The kernel build's per-block minima and leftmost argmins."""
     nb, bs = shape
     x = rng.integers(-100, 100, (nb, bs)).astype(np.float32)
     xj = jnp.asarray(x).astype(dtype)
-    val, idx = block_min(xj, interpret=True)
+    s = ops.build(xj.reshape(-1), bs)
     gval, gidx = kref.block_min_ref(xj)
-    np.testing.assert_allclose(np.asarray(val), np.asarray(gval))
-    np.testing.assert_array_equal(np.asarray(idx), np.asarray(gidx))
-
-
-@pytest.mark.parametrize("tile_rows", [1, 3, 8])
-def test_block_min_tiling(tile_rows, rng):
-    x = jnp.asarray(rng.standard_normal((13, 128)).astype(np.float32))
-    val, idx = block_min(x, tile_rows=tile_rows, interpret=True)
-    gval, gidx = kref.block_min_ref(x)
-    np.testing.assert_allclose(np.asarray(val), np.asarray(gval))
-    np.testing.assert_array_equal(np.asarray(idx), np.asarray(gidx))
+    np.testing.assert_allclose(np.asarray(s.bmin_val), np.asarray(gval))
+    np.testing.assert_array_equal(np.asarray(s.bmin_gidx) - np.arange(nb) * bs, np.asarray(gidx))
 
 
 @pytest.mark.parametrize("shape", [(8, 128), (4, 256)])
