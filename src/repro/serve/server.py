@@ -25,8 +25,13 @@ throughput over the serving interval.
 
 The engine is any ``(l, r) -> (idx, val)`` callable — typically a registry
 ``EngineSpec.query`` closed over its built state (``launch.serve`` wires
-exactly that). jax dispatch is thread-safe; ``workers > 1`` overlaps one
-batch's host-side partition/scatter work with another's device execution.
+exactly that). Each worker keeps one launch in flight ahead: when the
+next microbatch is already queued, it *issues* launch k+1 (the engine call:
+host-side partition, bounds to the device, asynchronous enqueue) before it
+*completes* launch k (wait, copy to the host, scatter, futures), so one
+launch's host path runs while the other's kernel computes. An engine that
+answers on the host simply runs serially. jax dispatch is thread-safe, so
+``workers > 1`` runs such pipelines side by side.
 
 **Mutation under live traffic**: constructed over a ``repro.update``
 ``OnlineEngine`` instead of a bare callable, the server also accepts
@@ -224,6 +229,26 @@ class _UpdateReq:
         self.deltas = deltas
         self.future: Future = Future()
         self.t_submit = t_submit
+
+
+class _Issued:
+    """One launch between its issue and its completion: the microbatch, the
+    pool that answered it, and the engine's answers (device arrays that may
+    still be computing, or host arrays) or the error its issue raised."""
+
+    __slots__ = ("mb", "reqs", "ver", "fs", "pool", "idx", "val", "splits", "kernel_long", "error")
+
+    def __init__(self, mb: MicroBatch, reqs, ver, fs):
+        self.mb, self.reqs, self.ver, self.fs = mb, reqs, ver, fs
+        self.pool: Optional[str] = None  # "primary" | "degraded" once routed
+        self.idx = self.val = None
+        self.splits: List[Tuple[int, int]] = []
+        self.kernel_long = 0
+        self.error: Optional[BaseException] = None
+
+    def computing(self) -> bool:
+        """True while an answer is a device array not yet ready."""
+        return any(isinstance(a, jax.Array) and not a.is_ready() for a in (self.idx, self.val))
 
 
 class ServeStats(NamedTuple):
@@ -447,6 +472,9 @@ class RMQServer:
         # Ranges past the sqrt(n) regime boundary that the kernel answered
         # because the hybrid was built without its long-path table.
         self._m_kernel_long = m.counter("serve_kernel_long_queries_total")
+        # Launches issued while the worker's previous launch was still
+        # computing on the device: how often the launch pipeline overlaps.
+        self._m_overlapped = m.counter("serve_launches_overlapped_total")
         self._m_restarts = m.counter("serve_worker_restarts_total")
         self._m_trips = m.counter("serve_breaker_trips_total")
         self._m_updates = {
@@ -836,95 +864,165 @@ class RMQServer:
             self._deaths.put(slot)
 
     def _worker_loop(self, slot: int = 0):
-        while True:
-            item = self._mbq.get()
-            if item is _STOP:
-                return
-            mb, reqs, ver, fs = item
-            try:
-                parts, splits, kernel_long, degraded = self._launch(mb, ver, fs)
-            except BaseException as e:
-                # Failed launch: its requests retry or fail — never the whole
-                # server. An injected crash additionally kills this worker
-                # thread (after the batch is requeued) to exercise the
-                # supervisor's restart path.
-                self._requeue_or_fail(mb, reqs, ver, fs, e)
-                if isinstance(e, InjectedFault) and e.kind == "crash":
-                    raise
-                continue
-            self._finish(mb, reqs, ver, fs, parts, splits, kernel_long, degraded)
+        """The launch pipeline, two deep: launch k+1 is issued before launch
+        k is completed, so k+1's host path runs while k computes.
 
-    def _launch_span(self, fs, ver, mb: MicroBatch, pool: str):
+        At most one launch is held issued but not completed, and only while
+        its answers are device arrays still computing; a launch answered on
+        the host completes at once, so such an engine runs the serial cycle.
+        With none held the worker blocks for the next microbatch; with one
+        held it takes the next only if one is already queued, and otherwise
+        completes the held launch at once: it never waits for new work while
+        answers are ready. A failed issue fails or requeues only its own
+        batch, after the held launch is completed; an injected crash then
+        kills this worker thread to exercise the supervisor's restart path.
+        """
+        held: Optional[_Issued] = None
+        while True:
+            if held is None:
+                item = self._mbq.get()
+            else:
+                try:
+                    item = self._mbq.get_nowait()
+                except queue.Empty:
+                    self._complete(held)
+                    held = None
+                    continue
+            if item is _STOP:
+                if held is not None:
+                    self._complete(held)
+                return
+            issued = self._issue(*item, prev=held)
+            if held is not None:
+                self._complete(held)
+            # Only a launch the device is still computing is worth holding:
+            # answers on the host, or an error, complete at once.
+            held = issued if issued.computing() else None
+            if held is None:
+                self._complete(issued)  # an error requeues or fails; a crash re-raises
+
+    def _launch_span(self, fs, ver, mb: MicroBatch, pool: str, overlapped: bool):
         """Context manager for one engine launch span under flush span ``fs``
         (the worker thread — cross-thread, so the parent is explicit)."""
         attrs = dict(self._trace_attrs)
         attrs["pool"] = pool
         attrs["padded"] = mb.padded_size
         attrs["queries"] = int(mb.n_queries)
+        attrs["overlapped"] = overlapped
         if ver is not None:
             attrs["version"] = ver.vid
         return self._tracer.span("launch", parent=fs, attrs=attrs)
 
-    def _launch(self, mb: MicroBatch, ver, fs=None):
-        """One engine launch -> (per-request parts, regime splits, long
-        ranges the kernel answered, degraded?).
+    def _issue(self, mb: MicroBatch, reqs, ver, fs, prev: Optional[_Issued]) -> _Issued:
+        """The first half of a launch: route it (the breaker), call the
+        engine, and start copying its device answers home. Never raises: an
+        error is kept on the returned launch for ``_complete``.
 
-        Routes to the degraded fallback while the breaker is open; otherwise
-        runs the primary engine, feeding the breaker's consecutive-failure
-        count on each outcome.
+        ``prev`` is the launch still held; this one counts as overlapped
+        when that one is still computing on the device.
         """
-        if self._use_degraded():
-            return self._launch_degraded(mb, ver, fs)
+        launch = _Issued(mb, reqs, ver, fs)
+        overlapped = prev is not None and prev.computing()
+        if overlapped:
+            self._m_overlapped.inc()
+        try:
+            if self._use_degraded():
+                launch.pool = "degraded"
+                launch.idx, launch.val = self._launch_degraded(mb, ver, fs, overlapped)
+            else:
+                launch.pool = "primary"
+                launch.idx, launch.val, launch.splits, launch.kernel_long = self._launch_primary(
+                    mb, ver, fs, overlapped
+                )
+        except BaseException as e:
+            launch.error = e
+            return launch
+        for a in (launch.idx, launch.val):
+            if isinstance(a, jax.Array):
+                a.copy_to_host_async()
+        return launch
+
+    def _complete(self, launch: _Issued):
+        """The second half of a launch: its answers on the host, scattered
+        to its requests, the breaker fed with the primary's outcome, and the
+        requests answered; or, on an error, retried or failed. An injected
+        crash is re-raised after its requests are requeued."""
+        mb, reqs, ver, fs = launch.mb, launch.reqs, launch.ver, launch.fs
+        err = launch.error
+        if err is None:
+            try:
+                idx, val = self._to_host(fs, launch.idx, launch.val)
+                with self._tracer.span("scatter", parent=fs):
+                    parts = scatter_back(mb, idx, val)
+            except BaseException as e:
+                err = e
+        if launch.pool == "primary":
+            if err is None:
+                self._breaker_success()
+            else:
+                self._breaker_failure()
+        if err is not None:
+            self._requeue_or_fail(mb, reqs, ver, fs, err)
+            if isinstance(err, InjectedFault) and err.kind == "crash":
+                raise err
+            return
+        self._finish(
+            mb, reqs, ver, fs, parts, launch.splits, launch.kernel_long, launch.pool == "degraded"
+        )
+
+    def _launch_primary(self, mb: MicroBatch, ver, fs, overlapped: bool):
+        """Call the primary engine -> (idx, val, regime splits, long ranges
+        the kernel answered)."""
         tr = self._tracer
         self._m_launches["primary"].inc()
-        try:
-            # Observe how the range-adaptive dispatcher (if any) splits
-            # this launch: a thread-local sink, so concurrent workers
-            # never see each other's splits.
-            splits: List[Tuple[int, int]] = []
-            kernel_long: List[int] = []
-            lsp = None
-            t0 = time.perf_counter()
-            with _hybrid.record_splits(lambda s, g: splits.append((s, g)), kernel_long.append):
-                cm = self._launch_span(fs, ver, mb, "primary") if tr.enabled else tr.span("launch")
-                with cm as lsp:
-                    if self._fault is not None:
-                        self._fault("worker_query")
-                    if ver is not None:
-                        if self._launch_gate is not None:
-                            with self._launch_gate:
-                                idx, val = self._online.query(ver.state, mb.l, mb.r)
-                                idx, val = np.asarray(idx), np.asarray(val)
-                        else:
+        # Observe how the range-adaptive dispatcher (if any) splits this
+        # launch: a thread-local sink, so concurrent workers never see each
+        # other's splits.
+        splits: List[Tuple[int, int]] = []
+        kernel_long: List[int] = []
+        lsp = None
+        t0 = time.perf_counter()
+        with _hybrid.record_splits(lambda s, g: splits.append((s, g)), kernel_long.append):
+            cm = (
+                self._launch_span(fs, ver, mb, "primary", overlapped)
+                if tr.enabled
+                else tr.span("launch")
+            )
+            with cm as lsp:
+                if self._fault is not None:
+                    self._fault("worker_query")
+                if ver is not None:
+                    if self._launch_gate is not None:
+                        with self._launch_gate:
                             idx, val = self._online.query(ver.state, mb.l, mb.r)
+                            idx, val = np.asarray(idx), np.asarray(val)
                     else:
-                        idx, val = self._query_fn(mb.l, mb.r)
-            self._h_launch["primary"].observe(time.perf_counter() - t0)
-            # The coalesced launch is power-of-two padded with trivial
-            # (0, 0) queries; the dispatcher routes ALL pads to one side
-            # (short when threshold >= 1, else long — real queries never
-            # leave that side short of the pad count), so subtracting
-            # from whichever side holds them leaves real-traffic splits.
-            pad = mb.l.size - mb.n_queries
-            splits = [(s - pad, g) if s >= pad else (s, g - pad) for s, g in splits]
-            if splits and tr.enabled and lsp is not None:
-                lsp.set_attr("short", sum(s for s, _ in splits))
-                lsp.set_attr("long", sum(g for _, g in splits))
-            idx, val = self._to_host(fs, idx, val)
-            with tr.span("scatter", parent=fs):
-                parts = scatter_back(mb, idx, val)
-        except BaseException:
-            self._breaker_failure()
-            raise
-        self._breaker_success()
-        return parts, splits, sum(kernel_long), False
+                        idx, val = self._online.query(ver.state, mb.l, mb.r)
+                else:
+                    idx, val = self._query_fn(mb.l, mb.r)
+        self._h_launch["primary"].observe(time.perf_counter() - t0)
+        # The coalesced launch is power-of-two padded with trivial (0, 0)
+        # queries; the dispatcher routes ALL pads to one side (short when
+        # threshold >= 1, else long — real queries never leave that side
+        # short of the pad count), so subtracting from whichever side holds
+        # them leaves real-traffic splits.
+        pad = mb.l.size - mb.n_queries
+        splits = [(s - pad, g) if s >= pad else (s, g - pad) for s, g in splits]
+        if splits and tr.enabled and lsp is not None:
+            lsp.set_attr("short", sum(s for s, _ in splits))
+            lsp.set_attr("long", sum(g for _, g in splits))
+        return idx, val, splits, sum(kernel_long)
 
-    def _launch_degraded(self, mb: MicroBatch, ver, fs=None):
+    def _launch_degraded(self, mb: MicroBatch, ver, fs, overlapped: bool):
         """Answer via the correct-but-slower fallback path (breaker open)."""
         tr = self._tracer
         self._m_launches["degraded"].inc()
         t0 = time.perf_counter()
-        cm = self._launch_span(fs, ver, mb, "degraded") if tr.enabled else tr.span("launch")
+        cm = (
+            self._launch_span(fs, ver, mb, "degraded", overlapped)
+            if tr.enabled
+            else tr.span("launch")
+        )
         with cm:
             if self._online is not None:
                 if self._degraded is None:
@@ -937,10 +1035,7 @@ class RMQServer:
             else:  # unreachable: __init__ validates breaker => degraded path
                 raise EngineFailure("breaker open and no fallback", retryable=False)
         self._h_launch["degraded"].observe(time.perf_counter() - t0)
-        idx, val = self._to_host(fs, idx, val)
-        with tr.span("scatter", parent=fs):
-            parts = scatter_back(mb, idx, val)
-        return parts, [], 0, True
+        return idx, val
 
     def _to_host(self, fs, idx, val):
         """A launch's results on the host: ``wait`` for the device, then

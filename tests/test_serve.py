@@ -7,9 +7,11 @@ add noise. End-to-end scatter-back under mixed range distributions runs
 through the real registry ``hybrid`` engine.
 """
 
+import functools
 import threading
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -540,3 +542,122 @@ def test_submit_min_version_gates_on_stale_servers():
     with RMQServer(_oracle_engine(x), ServeConfig(deadline_s=1e-4)) as srv:
         with pytest.raises(ValueError):
             srv.submit(l, r, min_version=0)
+
+
+# --- the launch pipeline: launch k+1 issued before launch k completes --------
+
+
+@functools.partial(jax.jit, static_argnames="spin")
+def _spin_echo(l, r, spin):
+    """(l, r) as the answers (idx, val), after ``spin`` steps of device work,
+    so the call returns device arrays well before they are ready."""
+    acc = jax.lax.fori_loop(0, spin, lambda i, c: c * 0.5 + 1.0, jnp.zeros(1 << 14, jnp.float32))
+    z = (acc[0] * 0).astype(jnp.int32)
+    return l + z, (r + z).astype(jnp.float32)
+
+
+class _GatedEngine:
+    """A fake engine answering ``(idx, val) = (l, r)`` from ``_spin_echo``,
+    as unready device arrays or, with ``host``, as numpy arrays. Every call
+    waits for ``release``, so a test can queue microbatches behind the
+    first (``entered`` is set once one is waiting); then it records which
+    of the ``watched`` futures were done."""
+
+    def __init__(self, host: bool = False, spin: int = 100_000):
+        self.host, self.spin = host, spin
+        self.entered, self.release = threading.Event(), threading.Event()
+        self.watched = []
+        self.calls = []  # per engine call: done flags of ``watched``
+        jax.block_until_ready(_spin_echo(jnp.zeros(4, jnp.int32), jnp.zeros(4, jnp.int32), spin=spin))
+
+    def __call__(self, l, r):
+        self.entered.set()
+        self.release.wait(60)
+        self.calls.append([f.done() for f in self.watched])
+        idx, val = _spin_echo(jnp.asarray(l), jnp.asarray(r), spin=self.spin)
+        return (np.asarray(idx), np.asarray(val)) if self.host else (idx, val)
+
+
+def _request(i):
+    l = np.arange(4 * i, 4 * i + 4, dtype=np.int32)
+    return l, l + 1
+
+
+def _wait_until(cond, timeout=30.0):
+    t_end = time.perf_counter() + timeout
+    while not cond():
+        assert time.perf_counter() < t_end, "timed out"
+        time.sleep(0.001)
+
+
+def _queue_two_launches(srv, eng):
+    """Request 0 parked in the engine's first call, request 1 flushed into
+    the microbatch queue behind it -> their futures."""
+    futs = [srv.submit(*_request(0))]
+    assert eng.entered.wait(30)
+    futs.append(srv.submit(*_request(1)))
+    _wait_until(lambda: srv._mbq.qsize() == 1)
+    eng.watched = futs
+    return futs
+
+
+def _assert_own_answers(futs):
+    for i, f in enumerate(futs):
+        res = f.result(timeout=60)
+        l, r = _request(i)
+        assert np.array_equal(res.idx, l) and np.array_equal(res.val, r.astype(np.float32))
+
+
+@pytest.mark.parametrize("host", [False, True], ids=["device-arrays", "host-arrays"])
+def test_next_launch_is_issued_before_the_held_one_completes(host):
+    """With device answers still computing, the engine is called for launch
+    1 while launch 0's future is unresolved, and launch 1 counts as
+    overlapped. With host answers launch 0 is completed at once, before
+    launch 1 is issued: the serial cycle, never counted."""
+    eng = _GatedEngine(host=host)
+    with RMQServer(eng, ServeConfig(deadline_s=0.0, max_batch=4, n=64)) as srv:
+        futs = _queue_two_launches(srv, eng)
+        eng.release.set()
+        _assert_own_answers(futs)
+        overlapped = srv.metrics.counter("serve_launches_overlapped_total").value
+    assert eng.calls == [[False, False], [host, False]]
+    assert overlapped == (0 if host else 1)
+    assert srv.stats().n_batches == 2
+
+
+@pytest.mark.parametrize("kind", ["error", "crash"])
+def test_issue_fault_on_the_next_launch_leaves_the_held_one_answered(kind):
+    """A fault as launch 1 is issued, with launch 0 in flight: launch 0 is
+    answered, launch 1's request is retried (after the worker restarts,
+    for a crash) and answered too; no future is left unresolved."""
+    from repro.fault import FaultPlan, FaultSpec
+
+    eng = _GatedEngine()
+    plan = FaultPlan(seed=5, specs={"worker_query": FaultSpec(at=(2,), kind=kind)})
+    cfg = ServeConfig(deadline_s=0.0, max_batch=4, n=64, max_retries=1, worker_backoff_s=0.005)
+    with RMQServer(eng, cfg, fault_plan=plan) as srv:
+        futs = _queue_two_launches(srv, eng)
+        eng.release.set()
+        _assert_own_answers(futs)
+        st = srv.stats()
+    # The fault fires before the engine call: the engine saw launch 0, then
+    # launch 1's retry, once launch 0 was answered.
+    assert eng.calls == [[False, False], [True, False]]
+    assert st.retried_requests == 1 and st.failed_requests == 0
+    assert st.worker_restarts == (1 if kind == "crash" else 0)
+
+
+def test_close_completes_the_launch_in_flight():
+    """close() while launch 0 is in flight and launch 1 queued: the worker
+    completes both before it stops, so both are answered, not closed."""
+    eng = _GatedEngine()
+    srv = RMQServer(eng, ServeConfig(deadline_s=0.0, max_batch=4, n=64)).start()
+    futs = _queue_two_launches(srv, eng)
+    closer = threading.Thread(target=srv.close)
+    closer.start()
+    _wait_until(lambda: srv._mbq.qsize() == 2)  # launch 1, then the stop
+    eng.release.set()
+    closer.join(60)
+    assert not closer.is_alive()
+    _assert_own_answers(futs)
+    assert srv.metrics.counter("serve_launches_overlapped_total").value == 1
